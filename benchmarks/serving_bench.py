@@ -575,10 +575,7 @@ def sharded_smoke() -> dict:
     from repro import engine as E
     from repro.configs import get_config
     from repro.models import registry as R
-    from repro.runtime import steps as ST
 
-    if not ST.supports_sharded_serving():
-        return {"skipped": "no jax.experimental.shard_map in this jax"}
     ndev = len(jax.devices())
     tp = min(4, ndev)
     cfg = dataclasses.replace(

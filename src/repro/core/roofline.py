@@ -14,8 +14,8 @@ All three inputs come from ``core.hlo_cost``'s structural HLO analysis
 per-op FLOP/byte breakdown carried on :class:`RooflineTerms` so reports can
 show *where* the counts come from.
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16 (394 TOPS int8), 819 GB/s HBM,
-~50 GB/s per ICI link.
+Hardware model: one chip's published peaks from ``CHIP_PEAKS``, keyed by
+the device kind JAX reports; the dry-run costs its cells against the v5e.
 """
 from __future__ import annotations
 
@@ -23,11 +23,37 @@ import dataclasses
 import json
 from typing import Dict, List, Optional
 
-# --- TPU v5e hardware constants (per chip) ---------------------------------
-PEAK_FLOPS_BF16 = 197e12
-PEAK_FLOPS_INT8 = 394e12
-HBM_BW = 819e9
-ICI_BW = 50e9   # per link
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks, with where they were published."""
+    bf16_flops: float       # FLOP/s
+    int8_ops: float         # OP/s
+    hbm_bw: float           # bytes/s
+    ici_bw: float           # bytes/s per chip-to-chip link
+    source: str
+
+
+# keyed by jax.Device.device_kind; a kind not listed here has no peaks
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    # 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, and
+    # 1,600 Gbit/s of interconnect per chip over its four links
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, int8_ops=393e12, hbm_bw=819e9,
+        ici_bw=1600e9 / 8 / 4,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; an unknown kind raises."""
+    if device_kind not in CHIP_PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them with their source to "
+                       f"CHIP_PEAKS (known: {sorted(CHIP_PEAKS)})")
+    return CHIP_PEAKS[device_kind]
+
+
+V5E = chip_peaks("TPU v5 lite")       # the dry-run's hardware model
 
 COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                   "collective-permute")
@@ -56,7 +82,7 @@ class RooflineTerms:
     collective_bytes: float
     collective_counts: Dict[str, int]
     model_flops: float = 0.0           # 6*N*D etc., "useful" flops
-    peak_flops: float = PEAK_FLOPS_BF16
+    peak_flops: float = V5E.bf16_flops
     bytes_per_device: Optional[dict] = None
     # per-op breakdown from hlo_cost (global = per-device x chips):
     # opcode -> {"flops": .., "bytes": .., "count": ..}
@@ -69,11 +95,11 @@ class RooflineTerms:
 
     @property
     def memory_s(self) -> float:
-        return self.hlo_bytes / (self.chips * HBM_BW)
+        return self.hlo_bytes / (self.chips * V5E.hbm_bw)
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / (self.chips * ICI_BW)
+        return self.collective_bytes / (self.chips * V5E.ici_bw)
 
     @property
     def bound(self) -> str:
@@ -123,7 +149,7 @@ class RooflineTerms:
 
 def from_compiled(cell: str, compiled, chips: int, *,
                   model_flops: float = 0.0,
-                  peak_flops: float = PEAK_FLOPS_BF16,
+                  peak_flops: float = V5E.bf16_flops,
                   hlo_text: Optional[str] = None) -> RooflineTerms:
     """Build RooflineTerms from a jax Compiled object.
 

@@ -11,10 +11,10 @@ The engine splits into three layers (docs/architecture.md):
 - ``ExecutorBackend`` — the narrow seam the dispatch core runs compiled
   steps through: the five step builders behind the process-wide
   ``runtime.steps.cached_*`` memos.  :class:`SingleDeviceExecutor` is
-  the legacy single-device step set; :class:`ShardedExecutor` runs the
-  same builders under ``jax.experimental.shard_map`` on a
-  tensor-parallel mesh axis (slot-axis sharding — bit-identical to the
-  single-device backend by construction, see runtime/steps.py).
+  the single-device step set; :class:`ShardedExecutor` runs the same
+  builders under ``jax.shard_map`` on a tensor-parallel mesh axis
+  (slot-axis sharding — bit-identical to the single-device backend by
+  construction, see runtime/steps.py).
 
 Everything host-side a request leaves behind between dispatches —
 ``SlotState`` progress, block tables, the preemption stash — lives
@@ -145,6 +145,16 @@ class ExecutorBackend:
         """Reject engine shapes this backend cannot serve (called once
         at Engine construction, before any lane compiles a step)."""
 
+    def place_params(self, params):
+        """Put a lane's params where this backend's steps run, once, at
+        lane construction — so no step call copies them."""
+        return params
+
+    def place_cache(self, cfg: ArchConfig, cache):
+        """Lay a cache out exactly as this backend's steps take it, so
+        the warmup compiles the signature every later tick reuses."""
+        return cache
+
     def slot_step(self, cfg: ArchConfig, *, mode: QuantMode,
                   temperature: float) -> Callable:
         raise NotImplementedError
@@ -166,12 +176,27 @@ class ExecutorBackend:
 
 
 class SingleDeviceExecutor(ExecutorBackend):
-    """The legacy step set: one device, one compiled step per (config,
-    shape) from the process-wide ``cached_*`` memos — a dedicated
-    engine and a multiplexed lane over the same config share one
-    compilation."""
+    """The single-device step set: one compiled step per (config, shape)
+    from the process-wide ``cached_*`` memos — a dedicated engine and a
+    multiplexed lane over the same config share one compilation.
+
+    ``device`` pins the engine's params and caches to one device (each
+    replica behind a ``ReplicaRouter`` on its own chip); the steps then
+    run where their committed inputs live.  ``None`` leaves state on
+    JAX's default device."""
 
     kind = "single"
+
+    def __init__(self, device=None):
+        self.device = device
+
+    def place_params(self, params):
+        if self.device is None:
+            return params
+        return jax.device_put(params, self.device)
+
+    def place_cache(self, cfg, cache):
+        return self.place_params(cache)
 
     def slot_step(self, cfg, *, mode, temperature):
         return ST.cached_slot_decode_step(cfg, mode=mode,
@@ -193,7 +218,7 @@ class SingleDeviceExecutor(ExecutorBackend):
 
 class ShardedExecutor(ExecutorBackend):
     """Tensor-parallel step set: the same ``make_*_step`` builders run
-    under ``jax.experimental.shard_map`` on the ``"model"`` axis of a
+    under ``jax.shard_map`` on the ``"model"`` axis of a
     host mesh (``launch.mesh.make_host_mesh``), sharded along the SLOT
     axis — each shard advances ``num_slots / tp`` rows with the full
     model replicated, which keeps every per-row float op in the exact
@@ -204,20 +229,13 @@ class ShardedExecutor(ExecutorBackend):
     sharding that costs nothing (per-row ``cache_index`` is batch-local
     already).
 
-    Restricted to the XLA 0.4.x-safe forward-only subset: no
-    collectives at all inside the step (feature-detected by
-    ``runtime.steps.supports_sharded_serving``, the serving twin of
-    ``supports_int8_grad_exchange``).  Sharded state is replica-private
-    (decode-contract rule 9): the mesh lives inside this backend and
-    never crosses an engine boundary."""
+    Params are replicated onto the mesh once, when a lane is built.
+    Sharded state is replica-private (decode-contract rule 9): the mesh
+    lives inside this backend and never crosses an engine boundary."""
 
     kind = "sharded"
 
     def __init__(self, tp: Optional[int] = None):
-        if not ST.supports_sharded_serving():
-            raise RuntimeError(
-                "sharded serving needs jax.experimental.shard_map "
-                "(see supports_sharded_serving)")
         ndev = len(jax.devices())
         self.tp = int(tp) if tp is not None else ndev
         if self.tp < 1:
@@ -233,6 +251,12 @@ class ShardedExecutor(ExecutorBackend):
             raise ValueError(
                 f"num_slots={eng.num_slots} must divide by tp={self.tp} "
                 f"(the pool shards along the slot axis)")
+
+    def place_params(self, params):
+        return ST.place_replicated(params, self.tp)
+
+    def place_cache(self, cfg, cache):
+        return ST.place_sharded_cache(cfg, cache, self.tp)
 
     def slot_step(self, cfg, *, mode, temperature):
         return ST.cached_sharded_slot_decode_step(
@@ -279,15 +303,16 @@ class _Lane:
         self.eng = eng
         self.tag = tag
         self.order = order                 # dense gid = order * S + sid
-        self.cfg, self.params = cfg, params
+        be = eng.backend
+        self.cfg, self.params = cfg, be.place_params(params)
         self.spec_k = spec_k               # 0 on lanes that can't draft
-        self.dcfg, self.dparams = dcfg, dparams
+        self.dcfg = dcfg
+        self.dparams = None if dparams is None else be.place_params(dparams)
         # hot-swap state: a retiring lane finishes its in-flight slots
         # but admission refuses new requests for it; epoch stamps when
         # the lane joined (0 = at engine construction)
         self.retiring = False
         self.epoch = 0
-        be = eng.backend
         mode, temp = eng.mode, eng.temperature
         self.step = be.slot_step(cfg, mode=mode, temperature=temp)
         # encdec/vlm: the prime dispatch that writes a slot's cross-K/V
@@ -320,8 +345,9 @@ class _Lane:
         self.tokens = np.zeros((S, 1), np.int32)
         self.index = np.zeros((S,), np.int32)
         self.spec = self.spec_k > 0
-        self.draft_cache = (R.init_cache(self.dcfg, S, eng.max_seq)
-                            if self.spec else None)
+        self.draft_cache = (eng.backend.place_cache(
+            self.dcfg, R.init_cache(self.dcfg, S, eng.max_seq))
+            if self.spec else None)
         self.krow = np.zeros((S,), np.int32)
         self.props = self.tok_mat = self.n_tok = None
         paged = eng.block_size is not None
@@ -343,10 +369,17 @@ class _Lane:
         physical KV blocks behind an all-trash block table."""
         eng = self.eng
         if eng.block_size:
-            return R.init_paged_cache(self.cfg, eng.num_slots,
-                                      eng.max_seq, eng.block_size,
-                                      eng.num_blocks)
-        return R.init_cache(self.cfg, eng.num_slots, eng.max_seq)
+            cache = R.init_paged_cache(self.cfg, eng.num_slots, eng.max_seq,
+                                       eng.block_size, eng.num_blocks)
+        else:
+            cache = R.init_cache(self.cfg, eng.num_slots, eng.max_seq)
+        return eng.backend.place_cache(self.cfg, cache)
+
+    def set_tables(self, tables: np.ndarray) -> None:
+        """Push a block-table mirror into the device cache, laid out like
+        the rest of the cache (no new step signature, no recompile)."""
+        self.cache = self.eng.backend.place_cache(
+            self.cfg, dict(self.cache, block_tables=jnp.asarray(tables)))
 
     def _chunk_step(self, chunk: int) -> Callable:
         """The compiled prefill step for one bucket size (memoized in
@@ -826,9 +859,7 @@ class DispatchCore:
                     # dispatch this tick gathers or scatters through it
                     for ln in lanes:
                         if ln.tables_dirty:
-                            ln.cache = dict(
-                                ln.cache,
-                                block_tables=jnp.asarray(ln.tables_np))
+                            ln.set_tables(ln.tables_np)
                             ln.tables_dirty = False
                 # 3) idle: nothing active -> jump to the next event
                 if total_active() == 0:
@@ -964,8 +995,7 @@ class DispatchCore:
                             torn = ln.tables_np.copy()
                             for sid in ln.torn:
                                 torn[sid, :] = 0
-                            ln.cache = dict(ln.cache,
-                                            block_tables=jnp.asarray(torn))
+                            ln.set_tables(torn)
                             ln.tables_dirty = True  # clean mirror repushed
                 if all_ready:
                     # resolve dispatch faults FIRST, over the union of

@@ -421,7 +421,8 @@ class Engine:
                         jnp.zeros((S,), jnp.int32),
                         jnp.zeros((S,), jnp.int32),
                         jnp.zeros((S,), bool))
-                    dcache = R.init_cache(ln.dcfg, S, self.max_seq)
+                    dcache = self.backend.place_cache(
+                        ln.dcfg, R.init_cache(ln.dcfg, S, self.max_seq))
                     _, dcache, _ = ln._propose_step(
                         ln.dparams, jnp.zeros((S, 1), jnp.int32), dcache,
                         jnp.zeros((S,), jnp.int32), jnp.zeros((S,), bool))

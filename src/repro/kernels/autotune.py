@@ -18,9 +18,11 @@ spills.  This module makes the tile choice explicit, modelled, and cached:
    the same flops/bytes accounting ``core.hlo_cost`` does structurally),
    plus a per-grid-step dispatch overhead.  Padding waste is penalized
    naturally because the padded problem is what gets executed.
-3. ``best_config`` — rank candidates, optionally refine the top few with a
-   measured timing backend (TPU only), and persist the winner in a JSON
-   cache keyed by (shape, mode, x-dtype, backend) so reruns are free.
+3. ``best_config`` — rank candidates and keep the analytic winner, or,
+   as an explicit tuning step, re-rank the top few with a timing backend
+   the caller passes (``measure_config`` on a chip); winners go to a
+   cache keyed by (shape, mode, x-dtype, backend).  The serving path
+   never times anything, so its tile choice is the same on every run.
 
 Cache file format (``autotune.json``)::
 
@@ -28,9 +30,8 @@ Cache file format (``autotune.json``)::
      "entries": {"64x4096x4096|w8a16|bf16|bias|tpu": {
          "bm": 64, "bn": 256, "bk": 512, "source": "measured"}}}
 
-Regenerate by deleting the file (env ``REPRO_AUTOTUNE_CACHE`` overrides the
-path; default ``~/.cache/repro_tpu/autotune.json``) — the analytic model
-refills it on first use; on a TPU backend the top candidates are re-timed.
+The cache is in memory unless ``REPRO_AUTOTUNE_CACHE`` names a file;
+then measured winners persist there and later processes read them back.
 """
 from __future__ import annotations
 
@@ -247,11 +248,9 @@ SCHEMA_VERSION = 1
 
 
 def default_cache_path() -> str:
-    env = os.environ.get("REPRO_AUTOTUNE_CACHE")
-    if env:
-        return os.path.expanduser(env)
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro_tpu",
-                        "autotune.json")
+    """``REPRO_AUTOTUNE_CACHE`` when set, else "" (an in-memory cache:
+    nothing is read from or written to disk)."""
+    return os.path.expanduser(os.environ.get("REPRO_AUTOTUNE_CACHE", ""))
 
 
 class AutotuneCache:
@@ -360,13 +359,15 @@ def get_cache() -> AutotuneCache:
 
 
 # ---------------------------------------------------------------------------
-# Timing backend (measured refinement, TPU only)
+# Timing backend (the explicit measured-tuning step, on a chip)
 # ---------------------------------------------------------------------------
 
 def measure_config(m: int, k: int, n: int, cfg: TileConfig, *,
                    mode: str = "w8a16", iters: int = 5) -> float:
     """Wall-clock one kernel launch at this config (compiled backends only;
-    interpret-mode timings are meaningless).  Returns seconds/call."""
+    interpret-mode timings are meaningless).  Returns seconds/call.  Pass
+    ``measure=lambda c: measure_config(m, k, n, c, mode=mode)`` to
+    :func:`best_config` with a file-backed cache to tune explicitly."""
     import time
 
     import jax
@@ -413,17 +414,15 @@ def best_config(m: int, k: int, n: int, *, mode: str = "w8a16",
     """Tuned (bm, bn, bk) for a problem; cached per (shape, mode, dtype,
     backend).
 
-    ``measure``: optional ``config -> seconds`` timing backend.  When given
-    (or when running on a real TPU backend, where ``measure_config`` is
-    used automatically), the top ``top_k_measure`` analytic candidates are
-    re-ranked by measurement.  Offline the analytic ranking decides alone.
+    Without ``measure`` the analytic ranking decides alone — what the
+    serving path does, so a run's tiles never depend on its timings.
+    ``measure`` (``config -> seconds``) re-ranks the top
+    ``top_k_measure`` analytic candidates by measurement: the explicit
+    tuning step.  A candidate the compiler refuses raises.
     """
     if backend is None:
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:               # pragma: no cover - defensive
-            backend = "cpu"
+        import jax
+        backend = jax.default_backend()
     cache = cache or get_cache()
     key = AutotuneCache.key(m, k, n, mode, x_dtype, out_dtype, has_bias,
                             backend)
@@ -434,20 +433,12 @@ def best_config(m: int, k: int, n: int, *, mode: str = "w8a16",
     ranked = rank_candidates(m, k, n, mode=mode, x_dtype=x_dtype,
                              out_dtype=out_dtype, has_bias=has_bias,
                              budget=budget, hw=hw)
-    if measure is None and backend == "tpu":
-        measure = lambda c: measure_config(m, k, n, c, mode=mode)
     source = "analytic"
     winner = ranked[0]
     if measure is not None:
-        timed = []
-        for c in ranked[:top_k_measure]:
-            try:
-                timed.append((measure(c), c))
-            except Exception:            # candidate failed to compile/run
-                continue
-        if timed:
-            winner = min(timed, key=lambda t: t[0])[1]
-            source = "measured"
+        timed = [(measure(c), c) for c in ranked[:top_k_measure]]
+        winner = min(timed, key=lambda t: t[0])[1]
+        source = "measured"
     cache.put(key, winner, source=source)
     return winner
 

@@ -10,13 +10,14 @@ serving hot loop.
 
 Shapes (native cache layout, no transposes):
 
+  valid_len (B,)              int32, scalar-prefetched into SMEM — slots
+                              < valid_len[b] participate (per-row: the
+                              slot engine's requests each sit at their
+                              own sequence frontier)
   q         (B, KV, G, hd)    fp — one query token, grouped per KV head
   k, v      (B, S, KV, hd)    int8 cache slots
   k_scale,  (B, S, KV)        fp32 per-(token, head) dequant scales
   v_scale
-  valid_len (B, 1)            int32 — slots < valid_len[b] participate
-                              (per-row: the slot engine's requests each
-                              sit at their own sequence frontier)
   k_new,    (B, 1, KV, hd)    fp — OPTIONAL: the current token's k/v
   v_new                       (append path: the cache holds only tokens
                               < valid_len; the new token rides along as
@@ -24,16 +25,17 @@ Shapes (native cache layout, no transposes):
                               rewrite inside the layer scan)
   out       (B, KV, G, hd)    fp
 
-Grid: (B, KV, S/blk_s) with the slot sweep innermost ("arbitrary");
-scratch carries the online-softmax state (acc[G, hd] f32, m[G] f32,
-l[G] f32) across the sweep, like the flash kernel.  Per-token scales are
-independent of the contracted hd axis, so they fold into score columns
-(k_scale) and prob columns (v_scale) instead of dequantizing K/V tiles
-into a widened copy — only the (blk_s, hd) tile ever exists at fp32, in
-VMEM, for the duration of one dot.  With ``k_new``/``v_new`` the final
+Grid: (B, S/blk_s) with the slot sweep innermost ("arbitrary").  Every
+block spans ALL KV heads: the TPU lowering only accepts blocks whose last
+two axes are whole tiles or whole array axes, so a (blk_s, 1, hd) slice
+of one head out of (S, KV, hd) is refused whenever KV > 1.  The kernel
+walks the heads with a static loop over the (blk_s, KV, hd) tile instead.
+Scratch carries the online-softmax state per head (acc[KV, G, hd],
+m[KV, G, 1], l[KV, G, 1], all f32) across the sweep, like the flash
+kernel.  Each head's (blk_s, hd) int8 tile is widened and scaled in VMEM
+only, for the duration of one dot.  With ``k_new``/``v_new`` the final
 sweep step folds the current token into the online softmax as one more
-score column before normalizing — closing the append path that the
-einsum fallback previously served alone.
+score column before normalizing.
 """
 from __future__ import annotations
 
@@ -44,21 +46,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams in newer JAX; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
-def _decode_attn_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, *rest,
+def _decode_attn_kernel(vl_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, *rest,
                         ns: int, blk_s: int, sm_scale: float, out_dtype,
                         has_new: bool):
     if has_new:
-        kn_ref, vn_ref, vl_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        kn_ref, vn_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
-        vl_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    sb = pl.program_id(2)
+        o_ref, acc_ref, m_ref, l_ref = rest
+    kvh = q_ref.shape[1]
+    sb = pl.program_id(1)
 
     @pl.when(sb == 0)
     def _init():
@@ -66,52 +65,58 @@ def _decode_attn_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)            # (G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)      # (blk_s, hd) int8 -> f32
-    ks = ks_ref[0, :, 0]                           # (blk_s,) f32
-    # q·(k*ks) == (q·k)*ks — the per-token scale is constant along hd, so
-    # dequant folds into the score column instead of a widened K tile.
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s * sm_scale * ks[None, :]
-
     slot = sb * blk_s + jax.lax.broadcasted_iota(jnp.int32, (1, blk_s), 1)
-    valid = slot < vl_ref[0, 0]                    # (1, blk_s)
-    s = jnp.where(valid, s, NEG_INF)
+    valid = slot < vl_ref[pl.program_id(0)]        # (1, blk_s)
+    for h in range(kvh):
+        q = q_ref[0, h].astype(jnp.float32)        # (G, hd)
+        # int8 -> f32 tile, dequantized by its per-token (row) scales
+        k = k_ref[0, :, h, :].astype(jnp.float32) * ks_ref[0, :, h:h + 1]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(valid, s * sm_scale, NEG_INF)    # (G, blk_s)
 
-    m_prev = m_ref[...]                            # (G,)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(valid, p, 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-    vs = vs_ref[0, :, 0]                           # (blk_s,) f32
-    v = v_ref[0, :, 0, :].astype(jnp.float32)      # (blk_s, hd) int8 -> f32
-    # fold v_scale into prob columns: p·(v*vs) == (p*vs)·v
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p * vs[None, :], v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+        m_prev = m_ref[h]                          # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        v = v_ref[0, :, h, :].astype(jnp.float32) * vs_ref[0, :, h:h + 1]
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
 
     @pl.when(sb == ns - 1)
     def _final():
-        acc, m_run, l_run = acc_ref[...], m_ref[...], l_ref[...]
-        if has_new:
-            # Append path: fold the current token's k/v (already fp — the
-            # caller dequantized its own-step quantization) into the online
-            # softmax as one extra column.  Also covers the empty-cache
-            # tick: every slot masked -> m_run = -inf -> alpha underflows
-            # to 0 and the output is exactly the new token's v.
-            kn = kn_ref[0, 0, 0, :].astype(jnp.float32)      # (hd,)
-            vn = vn_ref[0, 0, 0, :].astype(jnp.float32)      # (hd,)
-            s_new = jnp.sum(q * kn[None, :], axis=1) * sm_scale   # (G,)
-            m_fin = jnp.maximum(m_run, s_new)
-            alpha_f = jnp.exp(m_run - m_fin)
-            p_new = jnp.exp(s_new - m_fin)
-            l_run = l_run * alpha_f + p_new
-            acc = acc * alpha_f[:, None] + p_new[:, None] * vn[None, :]
-        denom = jnp.maximum(l_run, 1e-30)[:, None]
-        o_ref[0, 0] = (acc / denom).astype(out_dtype)
+        for h in range(kvh):
+            acc, m_run, l_run = acc_ref[h], m_ref[h], l_ref[h]
+            if has_new:
+                # Append path: fold the current token's k/v (already fp —
+                # the caller dequantized its own-step quantization) into
+                # the online softmax as one extra column.  Also covers the
+                # empty-cache tick: every slot masked -> m_run = -inf ->
+                # alpha underflows to 0 and the output is exactly v_new.
+                q = q_ref[0, h].astype(jnp.float32)            # (G, hd)
+                kn = kn_ref[0, 0, h:h + 1, :].astype(jnp.float32)  # (1, hd)
+                vn = vn_ref[0, 0, h:h + 1, :].astype(jnp.float32)
+                s_new = jnp.sum(q * kn, axis=1, keepdims=True) * sm_scale
+                m_fin = jnp.maximum(m_run, s_new)
+                alpha_f = jnp.exp(m_run - m_fin)
+                p_new = jnp.exp(s_new - m_fin)
+                l_run = l_run * alpha_f + p_new
+                acc = acc * alpha_f + p_new * vn
+            o_ref[0, h] = (acc / jnp.maximum(l_run, 1e-30)).astype(out_dtype)
+
+
+def _scratch(kvh: int, g: int, hd: int):
+    return [pltpu.VMEM((kvh, g, hd), jnp.float32),    # running context acc
+            pltpu.VMEM((kvh, g, 1), jnp.float32),     # running max
+            pltpu.VMEM((kvh, g, 1), jnp.float32)]     # running denominator
+
+
+def _valid_len(valid_len, b: int) -> jax.Array:
+    return jnp.broadcast_to(jnp.asarray(valid_len, jnp.int32).reshape(-1),
+                            (b,))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -128,11 +133,12 @@ def decode_attention_int8(q: jax.Array, k: jax.Array, ks: jax.Array,
     valid_len () or (B,) int32.  ``k_new``/``v_new`` (B, 1, KV, hd) fp:
     the append path's current-token k/v, folded in at the final sweep
     step.  G must be sublane-aligned (>= 8), hd lane-aligned (128
-    multiple), S a multiple of blk_s — `ops.decode_attention` pads.
+    multiple), S a multiple of blk_s (itself a multiple of 8) —
+    `ops.decode_attention` pads.
     """
     b, kvh, g, hd = q.shape
     s_slots = k.shape[1]
-    assert s_slots % blk_s == 0, (s_slots, blk_s)
+    assert s_slots % blk_s == 0 and blk_s % 8 == 0, (s_slots, blk_s)
     assert (k_new is None) == (v_new is None)
     ns = s_slots // blk_s
     has_new = k_new is not None
@@ -140,44 +146,29 @@ def decode_attention_int8(q: jax.Array, k: jax.Array, ks: jax.Array,
     kernel = functools.partial(
         _decode_attn_kernel, ns=ns, blk_s=blk_s, sm_scale=sm_scale,
         out_dtype=out_dtype, has_new=has_new)
-    vl = jnp.broadcast_to(jnp.asarray(valid_len).reshape(-1), (b,))
-    vl = vl.reshape(b, 1).astype(jnp.int32)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, g, hd), lambda bi, ki, si: (bi, ki, 0, 0)),
-        pl.BlockSpec((1, blk_s, 1, hd),
-                     lambda bi, ki, si: (bi, si, ki, 0)),
-        pl.BlockSpec((1, blk_s, 1), lambda bi, ki, si: (bi, si, ki)),
-        pl.BlockSpec((1, blk_s, 1, hd),
-                     lambda bi, ki, si: (bi, si, ki, 0)),
-        pl.BlockSpec((1, blk_s, 1), lambda bi, ki, si: (bi, si, ki)),
-    ]
+    cache_blk = pl.BlockSpec((1, blk_s, kvh, hd),
+                             lambda bi, si, vl: (bi, si, 0, 0))
+    scale_blk = pl.BlockSpec((1, blk_s, kvh), lambda bi, si, vl: (bi, si, 0))
+    row_blk = pl.BlockSpec((1, kvh, g, hd), lambda bi, si, vl: (bi, 0, 0, 0))
+    in_specs = [row_blk, cache_blk, scale_blk, cache_blk, scale_blk]
     operands = [q, k, ks, v, vs]
     if has_new:
-        in_specs += [
-            pl.BlockSpec((1, 1, 1, hd), lambda bi, ki, si: (bi, 0, ki, 0)),
-            pl.BlockSpec((1, 1, 1, hd), lambda bi, ki, si: (bi, 0, ki, 0)),
-        ]
+        new_blk = pl.BlockSpec((1, 1, kvh, hd),
+                               lambda bi, si, vl: (bi, 0, 0, 0))
+        in_specs += [new_blk, new_blk]
         operands += [k_new, v_new]
-    in_specs.append(pl.BlockSpec((1, 1), lambda bi, ki, si: (bi, 0)))
-    operands.append(vl)
 
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(b, ns), in_specs=in_specs,
+        out_specs=row_blk, scratch_shapes=_scratch(kvh, g, hd))
     return pl.pallas_call(
         kernel,
-        grid=(b, kvh, ns),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda bi, ki, si: (bi, ki, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), out_dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, hd), jnp.float32),      # running context acc
-            pltpu.VMEM((g,), jnp.float32),         # running max
-            pltpu.VMEM((g,), jnp.float32),         # running denominator
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands)
+    )(_valid_len(valid_len, b), *operands)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -199,13 +190,13 @@ def decode_attention_int8_paged(q: jax.Array, k: jax.Array, ks: jax.Array,
     new entry into its block after attention, so the cache holds tokens
     < valid_len and the new token rides as the append column).
 
-    The table is a scalar-prefetch operand (PrefetchScalarGridSpec): grid
-    step (bi, ki, si) streams block ``block_tables[bi, si]`` — the sweep
-    that already walked contiguous slot tiles now walks table entries, so
-    the kernel body is reused unchanged with ns=MB, blk_s=bs (its
-    position mask ``si*bs + i < valid_len`` is logical-position math
-    either way).  Entries past a row's frontier point at the reserved
-    trash block 0; their finite garbage is masked exactly like padding.
+    The table and the frontiers are scalar-prefetch operands: grid step
+    (bi, si) streams block ``block_tables[bi, si]`` — the sweep that
+    walks contiguous slot tiles now walks table entries, so the kernel
+    body is reused unchanged with ns=MB, blk_s=bs (its position mask
+    ``si*bs + i < valid_len`` is logical-position math either way).
+    Entries past a row's frontier point at the reserved trash block 0;
+    their finite garbage is masked exactly like padding.
     """
     b, kvh, g, hd = q.shape
     bs = k.shape[1]
@@ -218,49 +209,29 @@ def decode_attention_int8_paged(q: jax.Array, k: jax.Array, ks: jax.Array,
         _decode_attn_kernel(*refs, ns=mb, blk_s=bs, sm_scale=sm_scale,
                             out_dtype=out_dtype, has_new=has_new)
 
-    vl = jnp.broadcast_to(jnp.asarray(valid_len).reshape(-1), (b,))
-    vl = vl.reshape(b, 1).astype(jnp.int32)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, g, hd), lambda bi, ki, si, tbl: (bi, ki, 0, 0)),
-        pl.BlockSpec((1, bs, 1, hd),
-                     lambda bi, ki, si, tbl: (tbl[bi, si], 0, ki, 0)),
-        pl.BlockSpec((1, bs, 1),
-                     lambda bi, ki, si, tbl: (tbl[bi, si], 0, ki)),
-        pl.BlockSpec((1, bs, 1, hd),
-                     lambda bi, ki, si, tbl: (tbl[bi, si], 0, ki, 0)),
-        pl.BlockSpec((1, bs, 1),
-                     lambda bi, ki, si, tbl: (tbl[bi, si], 0, ki)),
-    ]
+    cache_blk = pl.BlockSpec((1, bs, kvh, hd),
+                             lambda bi, si, tbl, vl: (tbl[bi, si], 0, 0, 0))
+    scale_blk = pl.BlockSpec((1, bs, kvh),
+                             lambda bi, si, tbl, vl: (tbl[bi, si], 0, 0))
+    row_blk = pl.BlockSpec((1, kvh, g, hd),
+                           lambda bi, si, tbl, vl: (bi, 0, 0, 0))
+    in_specs = [row_blk, cache_blk, scale_blk, cache_blk, scale_blk]
     operands = [q, k, ks, v, vs]
     if has_new:
-        in_specs += [
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda bi, ki, si, tbl: (bi, 0, ki, 0)),
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda bi, ki, si, tbl: (bi, 0, ki, 0)),
-        ]
+        new_blk = pl.BlockSpec((1, 1, kvh, hd),
+                               lambda bi, si, tbl, vl: (bi, 0, 0, 0))
+        in_specs += [new_blk, new_blk]
         operands += [k_new, v_new]
-    in_specs.append(pl.BlockSpec((1, 1), lambda bi, ki, si, tbl: (bi, 0)))
-    operands.append(vl)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, kvh, mb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda bi, ki, si, tbl: (bi, ki, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, hd), jnp.float32),      # running context acc
-            pltpu.VMEM((g,), jnp.float32),         # running max
-            pltpu.VMEM((g,), jnp.float32),         # running denominator
-        ],
-    )
+        num_scalar_prefetch=2, grid=(b, mb), in_specs=in_specs,
+        out_specs=row_blk, scratch_shapes=_scratch(kvh, g, hd))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), out_dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables, *operands)
+    )(jnp.asarray(block_tables, jnp.int32), _valid_len(valid_len, b),
+      *operands)

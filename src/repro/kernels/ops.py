@@ -28,11 +28,12 @@ from repro.kernels import qmatmul as _k
 from repro.kernels import ref as _ref
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - defensive
-        return False
+def on_tpu() -> bool:
+    """The one backend predicate of the model and kernel code: True when
+    the default backend is a TPU, so Pallas kernels compile for the chip.
+    A compile rehearsal for a described (unattached) TPU patches this one
+    function to steer every TPU branch while JAX itself runs on the CPU."""
+    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
@@ -54,8 +55,8 @@ def _resolve_blocks(m: int, k: int, n: int, *, mode: str, x_dtype: str,
                     bn: Optional[int], bk: Optional[int]):
     """Fill unspecified block dims from the autotuner (explicit args win).
 
-    Runs at trace time on static shapes: the tuned choice is a Python int
-    baked into the compiled kernel, and the JSON cache makes reruns free.
+    Runs at trace time on static shapes: the analytic choice is a
+    Python int baked into the compiled kernel, the same on every run.
     """
     if bm is not None and bn is not None and bk is not None:
         return bm, bn, bk
@@ -87,8 +88,8 @@ def qmatmul(x, w: QTensor, bias: Optional[jax.Array] = None, *,
     m = x2.shape[0]
 
     w_scale = w.scale.reshape(-1)
-    use_pallas = _on_tpu() or interpret
-    run_interp = interpret and not _on_tpu()
+    use_pallas = on_tpu() or interpret
+    run_interp = interpret and not on_tpu()
 
     if x_q is not None:
         xq2 = x_q.values.reshape(-1, kdim)
@@ -174,7 +175,7 @@ def decode_attention(q, k, v, k_scale, v_scale, valid_len, *,
     if k_new is not None:
         k_new = k_new.reshape(b, 1, kvh, hd)
         v_new = v_new.reshape(b, 1, kvh, hd)
-    use_pallas = _on_tpu() or interpret
+    use_pallas = on_tpu() or interpret
     if block_tables is not None:
         nb, bs = k.shape[0], k.shape[1]
         ks = k_scale.reshape(nb, bs, kvh)
@@ -196,7 +197,7 @@ def decode_attention(q, k, v, k_scale, v_scale, valid_len, *,
             qp, kp, ks, vp, vs, jnp.asarray(valid_len),
             jnp.asarray(block_tables, jnp.int32), knp, vnp,
             sm_scale=sm_scale, out_dtype=out_dtype,
-            interpret=interpret and not _on_tpu())
+            interpret=interpret and not on_tpu())
         return out[:, :, :g, :hd]
     s_slots = k.shape[1]
     ks = k_scale.reshape(b, s_slots, kvh)
@@ -221,7 +222,7 @@ def decode_attention(q, k, v, k_scale, v_scale, valid_len, *,
     out = _da.decode_attention_int8(
         qp, kp, ksp, vp, vsp, jnp.asarray(valid_len), knp, vnp,
         blk_s=blk_s, sm_scale=sm_scale, out_dtype=out_dtype,
-        interpret=interpret and not _on_tpu())
+        interpret=interpret and not on_tpu())
     return out[:, :, :g, :hd]
 
 
@@ -236,7 +237,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
     b, sq, h, hd = q.shape
     skv = k.shape[1]
     out_dtype = out_dtype or q.dtype
-    use_pallas = _on_tpu() or interpret
+    use_pallas = on_tpu() or interpret
     qr = q.transpose(0, 2, 1, 3).reshape(b * h, sq, hd)
     kr = k.transpose(0, 2, 1, 3).reshape(b * h, skv, hd)
     vr = v.transpose(0, 2, 1, 3).reshape(b * h, skv, hd)
@@ -249,7 +250,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         out = _fa.flash_attention_bhsd(
             qp, kp, vp, blk_q=bq, blk_k=bk, causal=causal, window=window,
             kv_len=skv, sm_scale=hd ** -0.5, out_dtype=out_dtype,
-            interpret=interpret and not _on_tpu())
+            interpret=interpret and not on_tpu())
         out = out[:, :sq, :hd]
     else:
         out = _ref.flash_attention_ref(qr, kr, vr, causal=causal,

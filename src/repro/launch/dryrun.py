@@ -96,7 +96,7 @@ def build_cell(arch: str, shape_name: str, mesh, rules,
                              out_shardings=(p_sh, o_sh, None))
             with mesh:
                 lowered = jitted.lower(params, opt_state, b_specs, r_spec)
-            peak = RL.PEAK_FLOPS_BF16
+            peak = RL.V5E.bf16_flops
         else:
             mode = {"w8a16": W8A16, "w8a8": W8A8, "fp": FP}[quant]
             def qinit():
@@ -127,8 +127,7 @@ def build_cell(arch: str, shape_name: str, mesh, rules,
                                  donate_argnums=(2,))
                 with mesh:
                     lowered = jitted.lower(params, b_specs, cache)
-            peak = (RL.PEAK_FLOPS_INT8 if mode.w8a8
-                    else RL.PEAK_FLOPS_BF16)
+            peak = RL.V5E.int8_ops if mode.w8a8 else RL.V5E.bf16_flops
         return lowered, cfg.model_flops(shape), peak
 
 

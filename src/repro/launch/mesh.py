@@ -2,27 +2,16 @@
 
 Defined as FUNCTIONS (not module constants) so importing this module never
 touches jax device state — the dry-run must set XLA_FLAGS before first init.
-
-``jax.sharding.AxisType`` only exists in newer JAX (absent in 0.4.x); when
-it is missing we omit ``axis_types`` — the default (auto) behaviour matches
-what ``AxisType.Auto`` requests explicitly.
 """
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:          # JAX <= 0.4.x
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types when the installed JAX has them."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis under automatic (GSPMD) sharding."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

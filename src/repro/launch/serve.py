@@ -86,7 +86,7 @@ from repro.configs import get_config
 from repro.configs.base import ShapeSpec
 from repro.core import batching as bt
 from repro.core.qlinear import FP, W8A16, W8A8
-from repro.core.quant import quantize_tree, tree_weight_bytes
+from repro.core.quant import tree_weight_bytes
 from repro.models import registry as R
 from repro.runtime import steps as ST
 
@@ -297,13 +297,16 @@ def main(argv=None):
         lcfg = get_config(tag)
         if args.reduced:
             lcfg = lcfg.reduced()
-        lparams = R.init(jax.random.PRNGKey(args.seed + i), lcfg)
+        lkey = jax.random.PRNGKey(args.seed + i)
         if mode.enabled:
-            fp_bytes = tree_weight_bytes(lparams)
-            lparams = quantize_tree(lparams, min_size=2048)
+            fp_bytes = tree_weight_bytes(
+                jax.eval_shape(lambda k: R.init(k, lcfg), lkey))
+            lparams = R.init_quantized(lkey, lcfg)
             print(f"[quant] {tag} weights {fp_bytes / 1e6:.1f} MB -> "
                   f"{tree_weight_bytes(lparams) / 1e6:.1f} MB "
                   f"({args.quant})")
+        else:
+            lparams = R.init(lkey, lcfg)
         lanes[tag] = (lcfg, lparams)
     # the Table 4 curve / batch choice is measured on the first lane
     cfg, params = lanes[tags[0]]
@@ -369,9 +372,9 @@ def main(argv=None):
         dcfg = get_config(args.draft)
         if args.reduced:
             dcfg = dcfg.reduced()
-        dparams = R.init(jax.random.PRNGKey(args.seed + 2), dcfg)
-        if mode.enabled:
-            dparams = quantize_tree(dparams, min_size=2048)
+        dkey = jax.random.PRNGKey(args.seed + 2)
+        dparams = (R.init_quantized(dkey, dcfg) if mode.enabled
+                   else R.init(dkey, dcfg))
         draft = (dcfg, dparams)
     if args.replicas < 1 or args.tp < 1:
         print(f"[serve] --replicas and --tp must be >= 1 "
@@ -379,13 +382,9 @@ def main(argv=None):
         return 1
     backend = None
     if args.tp > 1:
-        if not ST.supports_sharded_serving():
-            print("[serve] --tp needs jax.experimental.shard_map "
-                  "(this jax has none); serve with --tp 1")
-            return 1
         try:
             backend = E.ShardedExecutor(tp=args.tp)
-        except (RuntimeError, ValueError) as e:
+        except ValueError as e:
             print(f"[serve] --tp rejected: {e}")
             return 1
         print(f"[serve] sharded executor: tp={args.tp} across "
@@ -404,8 +403,14 @@ def main(argv=None):
                   draft_layers=args.draft_layers or None,
                   backend=backend)
 
-    def build_engine(name=None):
+    def build_engine(name=None, replica=0):
         kw = dict(eng_kw, name=name)
+        if backend is None and args.replicas > 1:
+            # one-chip replicas: replica i keeps its params and caches on
+            # device i (round-robin when replicas outnumber devices)
+            devs = jax.devices()
+            kw["backend"] = E.SingleDeviceExecutor(
+                device=devs[replica % len(devs)])
         return (E.Engine(models=lanes, **kw) if args.models
                 else E.Engine(cfg, params, **kw))
 
@@ -464,7 +469,7 @@ def main(argv=None):
                   "same fired list on every replica")
             return 1
         try:
-            fleet = [eng] + [build_engine(f"replica{i}")
+            fleet = [eng] + [build_engine(f"replica{i}", i)
                              for i in range(1, args.replicas)]
         except ValueError as e:
             print(f"[engine] config rejected: {e}")
@@ -567,4 +572,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

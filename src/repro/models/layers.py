@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.core.qlinear import QuantMode, FP, init_linear, linear
 from repro.core.quant import QTensor
+from repro.kernels import ops as kops
 from repro.runtime.sharding import constrain
 
 Array = jax.Array
@@ -367,16 +368,15 @@ def attention(p: dict, x: Array, cfg: AttnConfig, *,
         g = h // kvh                            # heads per KV group
         if valid_len is None:
             valid_len = cache_index + s
-        if (quantized and s == 1 and jax.default_backend() == "tpu"):
+        if quantized and s == 1 and kops.on_tpu():
             # Fused Pallas decode attention: streams the int8 cache and
-            # dequantizes tile-by-tile in VMEM (per-token scales folded
-            # into score/prob columns), removing the decode path's
+            # dequantizes tile-by-tile in VMEM (each tile's rows scaled
+            # by their per-token scales), removing the decode path's
             # dominant memory term — the materialized dequantized cache.
             # Append path: the cache holds tokens < cache_index and the
             # current token's k/v ride along as an extra kernel operand,
             # so the fused kernel now serves ALL quantized decode, not
             # only the in-scan-update (non-append) variant.
-            from repro.kernels import ops as kops
             if paged:
                 # physical blocks stream through the per-row table inside
                 # the kernel (scalar-prefetch grid); the cache holds tokens
@@ -450,12 +450,11 @@ def attention(p: dict, x: Array, cfg: AttnConfig, *,
         vfull = _expand_kv(v, h)
         causal = cfg.causal and xattn_kv is None
         window = cfg.window if xattn_kv is None else None
-        if jax.default_backend() == "tpu" and xattn_valid_len is None:
+        if kops.on_tpu() and xattn_valid_len is None:
             # Pallas fused flash kernel: probs never leave VMEM (the
             # Unified-Buffer discipline); HBM traffic = Q+K+V+O.  The
             # kernel carries no per-row KV frontier, so a primed source
             # with per-row valid lengths takes the masked chunked path.
-            from repro.kernels import ops as kops
             out = kops.flash_attention(q, kfull, vfull, causal=causal,
                                        window=window)
         else:

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.core.qlinear import FP, QuantMode, init_linear, linear
 from repro.core.quant import QTensor
+from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.models import transformer as TF
 from repro.runtime.sharding import constrain
@@ -109,7 +110,7 @@ def moe_ffn(p: dict, x: Array, cfg: ArchConfig, *,
     # expert FFNs as grouped matmuls over all rows at once.  bf16 operands
     # on TPU (MXU-native); f32 on CPU, whose dot runtime lacks the batched
     # BF16xBF16=F32 thunk.
-    cdt = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+    cdt = jnp.bfloat16 if kops.on_tpu() else jnp.float32
 
     def emm(w, t):                                         # (B,E,C,D)x(E,D,F)
         if isinstance(w, QTensor):

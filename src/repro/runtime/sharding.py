@@ -125,11 +125,9 @@ _CTX = _Ctx()
 
 
 class manual_axes:
-    """Declare mesh axes as manual (shard_map) for constrain().
-
-    Newer JAX exposes the manual set on the abstract mesh; on 0.4.x there is
-    no in-trace introspection, so the step wrapper declares it explicitly
-    around the shard_map body.
+    """Declare mesh axes as manual (shard_map) for constrain(): the step
+    wrapper enters it around a shard_map body, and constrain() leaves
+    the declared axes out of its sharding constraints.
     """
 
     def __init__(self, axes):

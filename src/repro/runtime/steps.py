@@ -62,23 +62,11 @@ def make_loss_fn(cfg: ArchConfig, *, mode: QuantMode = FP,
 # int8 cross-pod gradient exchange
 # ---------------------------------------------------------------------------
 
-def supports_int8_grad_exchange() -> bool:
-    """True when the installed XLA can partition the int8 cross-pod
-    gradient exchange.  The partitioner bundled with JAX 0.4.x aborts
-    (``Check failed: sharding.IsManualSubgroup()``) when partitioning a
-    scan *backward* pass under partial-manual shard_map — and every model
-    here scans over layers — so the exchange needs the newer partitioner
-    that ships alongside ``jax.shard_map``."""
-    return hasattr(jax, "shard_map")
-
-
 def _int8_allreduce_pod(g: jax.Array, key: jax.Array) -> jax.Array:
     """Unbiased int8 all-reduce over the manual "pod" axis.
 
     quantize (stochastic) -> all_gather int8 (+ scalar scales) -> local
     dequant-sum.  Wire bytes: 1B/elem vs 2-4B for a raw all-reduce.
-    Only reachable on JAX versions whose partitioner handles collectives
-    under partial-manual shard_map (see supports_int8_grad_exchange).
     """
     scale = compute_scale(g, bits=8)
     qmin, qmax = int_bounds(8)
@@ -103,14 +91,6 @@ def make_train_step(cfg: ArchConfig, optimizer: Optimizer, *,
 
     use_int8 = (grad_compression == "int8" and mesh is not None
                 and "pod" in mesh.axis_names)
-    if use_int8 and not supports_int8_grad_exchange():
-        import warnings
-        warnings.warn(
-            "int8 grad exchange needs a partitioner that handles scan "
-            "backward under partial-manual shard_map (JAX with "
-            "jax.shard_map); falling back to uncompressed gradients",
-            RuntimeWarning, stacklevel=2)
-        use_int8 = False
 
     def _core(params, opt_state, batch, rng):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
@@ -134,22 +114,14 @@ def make_train_step(cfg: ArchConfig, optimizer: Optimizer, *,
         bspec = jax.tree_util.tree_map(lambda _: P("pod"),
                                        {"tokens": 0, "labels": 0})
         def _core_manual(*args):
-            # declare "pod" manual for constrain() — 0.4.x shard_map has no
-            # in-trace manual-axis introspection
+            # declare "pod" manual for constrain()
             with S.manual_axes({"pod"}):
                 return _core(*args)
 
-        specs = dict(in_specs=(pspec, pspec, bspec, P()),
-                     out_specs=(pspec, pspec, pspec))
-        if hasattr(jax, "shard_map"):
-            core = jax.shard_map(_core_manual, mesh=mesh, axis_names={"pod"},
-                                 check_vma=False, **specs)
-        else:                          # JAX 0.4.x: partial-manual via auto=
-            from jax.experimental.shard_map import shard_map
-            auto = frozenset(mesh.axis_names) - {"pod"}
-            core = shard_map(_core_manual, mesh=mesh, check_rep=False,
-                             auto=auto, **specs)
-        return core
+        return jax.shard_map(_core_manual, mesh=mesh, axis_names={"pod"},
+                             check_vma=False,
+                             in_specs=(pspec, pspec, bspec, P()),
+                             out_specs=(pspec, pspec, pspec))
     return _core
 
 
@@ -667,12 +639,10 @@ def cached_draft_propose_step(cfg: ArchConfig, *, mode: QuantMode = FP,
 # computes for those rows — which is the whole point: head/expert tensor
 # parallelism needs a cross-shard psum whose float adds reassociate, and
 # bit parity with the single-device engine (the repo's gating currency)
-# would be lost.  Slot sharding costs no collectives at all, which also
-# keeps us inside the XLA 0.4.x-safe subset: the partitioner bundled
-# with JAX 0.4.x aborts on all-gather/ppermute under shard_map even in
-# forward-only code (and on any scan backward — see
-# supports_int8_grad_exchange), but forward scans with zero collectives
-# partition fine.
+# would be lost.  Slot sharding needs no collective inside the step;
+# the paged merge below is the one cross-shard exchange.  Pallas kernels
+# inside the steps carry no varying-manual-axes annotations, so the
+# shard_map runs with ``check_vma=False``.
 #
 # Paged leaves are the one wrinkle: physical KV blocks are shared across
 # slots (hence across shards), so each shard gets a replicated COPY,
@@ -682,20 +652,6 @@ def cached_draft_propose_step(cfg: ArchConfig, *, mode: QuantMode = FP,
 # partition real blocks; only reserved trash block 0 takes multi-shard
 # garbage writes, and block 0 is never read).
 
-def supports_sharded_serving() -> bool:
-    """True when the installed JAX can run the sharded serving steps.
-
-    The serving twin of :func:`supports_int8_grad_exchange`, with a
-    weaker requirement: the steps are forward-only and collective-free,
-    so the 0.4.x partitioner handles them — we only need
-    ``jax.experimental.shard_map`` to exist."""
-    try:
-        from jax.experimental.shard_map import shard_map  # noqa: F401
-    except ImportError:
-        return False
-    return True
-
-
 @functools.lru_cache(maxsize=None)
 def _sharded_mesh(tp: int):
     from repro.launch.mesh import make_host_mesh
@@ -703,9 +659,8 @@ def _sharded_mesh(tp: int):
 
 
 def _shard_map(tp: int):
-    from jax.experimental.shard_map import shard_map
-    return functools.partial(shard_map, mesh=_sharded_mesh(tp),
-                             check_rep=False)
+    return functools.partial(jax.shard_map, mesh=_sharded_mesh(tp),
+                             check_vma=False)
 
 
 def _sharded_cache_specs(cfg: ArchConfig, cache: dict):
@@ -794,6 +749,24 @@ def _rep_and_row(tp: int):
     return NamedSharding(mesh, P()), NamedSharding(mesh, P("model"))
 
 
+def place_sharded_cache(cfg: ArchConfig, cache: dict, tp: int) -> dict:
+    """Lay a pooled cache out the way the sharded steps take it (slot
+    leaves split on their slot axis, paged blocks replicated), so a fresh
+    cache and a step's output cache present one input signature."""
+    from jax.sharding import NamedSharding
+    in_c, _, _, _ = _sharded_cache_specs(cfg, cache)
+    mesh = _sharded_mesh(tp)
+    return {k: jax.device_put(v, NamedSharding(mesh, in_c[k]))
+            for k, v in cache.items()}
+
+
+def place_replicated(tree, tp: int):
+    """Copy ``tree`` onto every device of the tp-way mesh once, in the
+    replicated layout the sharded steps take their params in — so a
+    step call never re-broadcasts them from one device."""
+    return jax.device_put(tree, _rep_and_row(tp)[0])
+
+
 def make_sharded_slot_decode_step(cfg: ArchConfig, *, mode: QuantMode = FP,
                                   temperature: float = 0.0,
                                   tp: int = 1) -> Callable:
@@ -866,8 +839,7 @@ def make_sharded_verify_step(cfg: ArchConfig, *, mode: QuantMode = FP,
                              k: int, temperature: float = 0.0,
                              tp: int = 1) -> Callable:
     """Tensor-parallel :func:`make_verify_step` — the wide speculative
-    verify scan, slot-axis sharded.  The scan is forward-only and
-    collective-free, so it stays inside the 0.4.x-safe subset."""
+    verify scan, slot-axis sharded, forward-only and collective-free."""
     base = make_verify_step(cfg, mode=mode, k=k, temperature=temperature)
     has_rng = temperature > 0.0
 

@@ -4,10 +4,8 @@ import tempfile
 
 import pytest
 
-# tests run against src/ without installation; tests/ itself must also be
-# importable for the _hypothesis_compat fallback shim
+# tests run against src/ without installation
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-sys.path.insert(0, os.path.dirname(__file__))
 
 # Smoke tests and benches must see the real single-CPU device topology.
 # (Only launch/dryrun.py forces 512 host devices, in its own process.)

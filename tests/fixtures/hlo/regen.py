@@ -73,7 +73,6 @@ def psum_main():
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
     mesh = jax.make_mesh((4,), ("x",))
     a = jax.ShapeDtypeStruct((128, 256), jnp.float32)
@@ -89,7 +88,7 @@ def psum_main():
     # all-reduce INSIDE a scanned while: collective bytes/counts must be
     # multiplied by the 8-iteration trip count.
     def scan_psum(x, w):
-        @partial(shard_map, mesh=mesh, in_specs=(P(None, "x"), P("x", None)),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P(None, "x"), P("x", None)),
                  out_specs=P(None, None))
         def mm(xs, ws):
             return jax.lax.psum(xs @ ws, "x")

@@ -1,9 +1,6 @@
 """Latency-aware batching: Table 4 reproduction + scheduler properties."""
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # offline: no network, no pip
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import batching as bt
 

@@ -1,9 +1,6 @@
 """Data pipeline: determinism, resumability, host sharding."""
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # offline: no network, no pip
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.data import SyntheticLMData
 

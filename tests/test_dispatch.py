@@ -15,7 +15,6 @@ import pytest
 from repro import engine as E
 from repro.configs import get_config
 from repro.models import registry as R
-from repro.runtime import steps as ST
 from repro.runtime.watchdog import StepWatchdog
 
 KEY = jax.random.PRNGKey(0)
@@ -71,8 +70,6 @@ def test_abstract_backend_provides_no_steps(dense_setup):
 
 
 def test_sharded_executor_rejects_bad_tp():
-    if not ST.supports_sharded_serving():
-        pytest.skip("no shard_map in this jax")
     with pytest.raises(ValueError, match="tp must be >= 1"):
         E.ShardedExecutor(tp=0)
     ndev = len(jax.devices())
@@ -83,8 +80,6 @@ def test_sharded_executor_rejects_bad_tp():
 def test_sharded_executor_validates_slot_divisibility(dense_setup):
     """A slot pool that does not divide across the mesh is rejected at
     Engine construction, before any step compiles."""
-    if not ST.supports_sharded_serving():
-        pytest.skip("no shard_map in this jax")
     cfg, params = dense_setup
     b = E.ShardedExecutor(tp=1)
     b.tp = 3                           # a mesh width 4 slots can't fill
@@ -92,13 +87,39 @@ def test_sharded_executor_validates_slot_divisibility(dense_setup):
         E.Engine(cfg, params, num_slots=4, max_seq=16, backend=b)
 
 
+_COMPILES = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda event, secs, **kw: _COMPILES.append(event)
+    if event == "/jax/core/compile/backend_compile_duration" else None)
+
+
+@pytest.mark.parametrize("backend", ["default", "pinned", "sharded"])
+def test_warmup_compiles_everything_serve_runs(dense_setup, backend):
+    """Each backend lays its params, caches and pushed block tables out
+    the way its steps take them, so after ``warmup`` a paged serve —
+    fresh cache, step outputs, table pushes — compiles nothing.  A
+    pinned replica or a sharded engine whose inputs changed layout from
+    one tick to the next would recompile its step mid-serve."""
+    cfg, params = dense_setup
+    be = {"default": None,
+          "pinned": E.SingleDeviceExecutor(device=jax.devices()[0]),
+          "sharded": E.ShardedExecutor(tp=1)}[backend]
+    eng = E.Engine(cfg, params, num_slots=4, max_seq=16, block_size=4,
+                   backend=be)
+    eng.warmup()
+    before = len(_COMPILES)
+    rep = eng.serve(E.synthetic_requests(
+        8, rate_per_s=2000.0, vocab=cfg.vocab, prompt_len=4,
+        max_new_tokens=5))
+    assert len(rep.results) == 8
+    assert len(_COMPILES) == before
+
+
 def test_backends_are_bitwise_interchangeable(dense_setup):
     """The conformance gate: the same engine shape served through the
     SingleDeviceExecutor and through a ShardedExecutor(tp=1) produces
     bit-identical outputs and identical accounting — the backend seam
     carries steps, not behavior."""
-    if not ST.supports_sharded_serving():
-        pytest.skip("no shard_map in this jax")
     cfg, params = dense_setup
     reqs = E.synthetic_requests(16, rate_per_s=2000.0, vocab=cfg.vocab,
                                 prompt_len=4, max_new_tokens=5)

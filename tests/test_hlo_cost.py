@@ -223,6 +223,16 @@ class TestRooflineTerms:
         t = RL.from_compiled("mm", c, chips=1, model_flops=2 * 64 * 128 * 32)
         assert t.hlo_flops == t.model_flops
 
+    def test_peaks_are_keyed_by_device_kind(self):
+        """The v5e's published peaks, with their source; a device kind
+        with no published peaks is an error, never a default."""
+        v5e = RL.chip_peaks("TPU v5 lite")
+        assert (v5e.bf16_flops, v5e.int8_ops, v5e.hbm_bw) == \
+            (197e12, 393e12, 819e9)
+        assert "TPU v5e" in v5e.source
+        with pytest.raises(KeyError, match="no published peaks"):
+            RL.chip_peaks("cpu")
+
 
 def test_watchdog_detects_stragglers():
     from repro.runtime.watchdog import StepWatchdog

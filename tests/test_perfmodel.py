@@ -2,10 +2,7 @@
 import math
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # offline: no network, no pip
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import perfmodel as pm
 

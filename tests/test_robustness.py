@@ -9,10 +9,7 @@ import warnings
 
 import jax
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # offline: no network, no pip
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro import engine as E
 from repro.configs import get_config

@@ -11,7 +11,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import make_host_mesh
 from repro.runtime import sharding as S
-from repro.runtime import steps as ST
 
 
 def _mesh22():
@@ -146,10 +145,6 @@ def test_multipod_compile_subprocess(arch):
     assert "OK" in r.stdout
 
 
-@pytest.mark.skipif(
-    not ST.supports_int8_grad_exchange(),
-    reason="XLA in JAX 0.4.x aborts on scan backward under partial-manual "
-           "shard_map (IsManualSubgroup CHECK); exchange needs newer JAX")
 def test_grad_compression_compiles_and_uses_int8_collectives():
     """int8 cross-pod gradient exchange: the compiled HLO must move the
     gradients over an s8 collective."""
