@@ -39,8 +39,8 @@ see "Overload & failure semantics" in ``docs/serving.md``.
 from repro.engine.dispatch import (DispatchCore, ExecutorBackend,
                                    ShardedExecutor, SingleDeviceExecutor)
 from repro.engine.engine import (Engine, EngineReport, EngineRequest,
-                                 RequestResult, reference_outputs,
-                                 synthetic_requests)
+                                 RequestResult, WarmupReport,
+                                 reference_outputs, synthetic_requests)
 from repro.engine.faults import FAULT_KINDS, Fault, FaultPlan
 from repro.engine.router import ReplicaRouter, RouterReport
 from repro.engine.scheduler import SlotScheduler
@@ -52,5 +52,5 @@ __all__ = [
     "ExecutorBackend", "FAULT_KINDS", "Fault", "FaultPlan",
     "ReplicaRouter", "RequestResult", "RequestTooLong", "RouterReport",
     "ShardedExecutor", "SingleDeviceExecutor", "SlotPool", "SlotScheduler",
-    "SlotState", "reference_outputs", "synthetic_requests",
+    "SlotState", "WarmupReport", "reference_outputs", "synthetic_requests",
 ]

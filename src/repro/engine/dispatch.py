@@ -492,6 +492,69 @@ class DispatchOutcome:
     wall: float = 0.0                 # measured host time
 
 
+class _TickSpans:
+    """The tick loop's profiler spans (docs/serving.md, "Profiling a
+    serve"), written through ``jax.profiler`` so they share the device
+    trace's clock.  Each dispatching iteration is one ``engine.tick``
+    step span (``step_num`` the tick's index; ``rows`` the positions it
+    advanced, ``prompt_rows`` those teacher-forced from a prompt), which
+    holds one span per host phase at a time: ``engine.admit``,
+    ``engine.prefill``, ``engine.draft``, ``engine.dispatch``,
+    ``engine.pull``, ``engine.bookkeep``.  Time with no slot active,
+    the admission of an empty pool and the sleep until the next event,
+    is one ``engine.wait`` span.  With the profiler off a span costs
+    one to two microseconds of host time."""
+
+    def __init__(self):
+        self.outer = self.inner = self.inner_name = None
+        self.in_tick = False
+        self.rows = self.prompt_rows = 0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+
+    def tick(self, num: int) -> None:
+        """Open tick ``num`` (a no-op while a tick is open)."""
+        if not self.in_tick:
+            # a span's clock starts when it is made: close the wait first
+            self.close()
+            self.outer = jax.profiler.StepTraceAnnotation(
+                "engine.tick", step_num=num)
+            self.outer.__enter__()
+            self.in_tick = True
+            self.rows = self.prompt_rows = 0
+
+    def wait(self) -> None:
+        self.close()
+        self.outer = jax.profiler.TraceAnnotation("engine.wait")
+        self.outer.__enter__()
+
+    def phase(self, name: str) -> None:
+        """Close the open phase and open ``name`` (a no-op if open)."""
+        if name != self.inner_name:
+            self._close_inner()
+            self.inner = jax.profiler.TraceAnnotation(name)
+            self.inner.__enter__()
+            self.inner_name = name
+
+    def _close_inner(self) -> None:
+        if self.inner is not None:
+            self.inner.__exit__(None, None, None)
+            self.inner = self.inner_name = None
+
+    def close(self) -> None:
+        self._close_inner()
+        if self.outer is not None:
+            if self.in_tick:
+                self.outer.set_metadata(rows=self.rows,
+                                        prompt_rows=self.prompt_rows)
+            self.outer.__exit__(None, None, None)
+            self.outer, self.in_tick = None, False
+
+
 class DispatchCore:
     """The tick loop: ingest -> (preempt) -> admit -> chunk prefill ->
     draft/propose -> fused or verify dispatch per lane -> host
@@ -640,11 +703,14 @@ class DispatchCore:
         limit = max_ticks if max_ticks is not None else \
             (sum(len(r.prompt) + r.max_new_tokens for r in reqs) + 16) * 4
 
-        with warnings.catch_warnings():
+        with warnings.catch_warnings(), _TickSpans() as spans:
             # CPU backends warn that donated buffers were not usable
             warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
             while (i < len(reqs) or sched.pending or total_active()
                    or ctl_i < len(ctl)):
+                # the previous iteration's spans end here, so a control
+                # op (a profiler start or stop) runs outside every span
+                spans.close()
                 # 0) hot-swap control: run every op the clock has passed;
                 #    each may admit or retire a lane, so refresh the lane
                 #    snapshot (append-only during a serve — gid mapping
@@ -663,6 +729,14 @@ class DispatchCore:
                         break
                     now = max(now, ctl[ctl_i][0])
                     continue
+                # an iteration that starts with a slot active dispatches:
+                # it is a tick; with none active it waits, unless its
+                # admission fills a slot, and the tick starts there
+                if total_active():
+                    spans.tick(ticks)
+                    spans.phase("engine.admit")
+                else:
+                    spans.wait()
                 # 1) ingest everything that has arrived by `now`
                 while i < len(reqs) and reqs[i].arrival_s <= now:
                     sched.push(reqs[i])
@@ -838,9 +912,10 @@ class DispatchCore:
                         # its cost lands on this tick's clock (resume
                         # re-primes: reconstructed, never trusted)
                         src, n_valid = _padded_source(ln.cfg, req)
-                        ln.cache = ln._prime_step(
-                            ln.params, src, ln.cache,
-                            jnp.asarray(st.sid, jnp.int32), n_valid)
+                        with jax.profiler.TraceAnnotation("engine.prefill"):
+                            ln.cache = ln._prime_step(
+                                ln.params, src, ln.cache,
+                                jnp.asarray(st.sid, jnp.int32), n_valid)
                         tick_lanes.add(ln.tag)
                     left = len(st.prompt) - 1 - st.pos
                     if eng.prefill_chunk and left > 0:
@@ -893,6 +968,8 @@ class DispatchCore:
                     else:
                         now = max(now, target)
                     continue
+                spans.tick(ticks)
+                spans.phase("engine.prefill")
                 # 4) chunked prefill: each mid-prefill slot writes one
                 #    bucketed chunk of teacher-forced prompt state in a
                 #    single dispatch (admission-to-first-token shrinks
@@ -912,6 +989,8 @@ class DispatchCore:
                             jnp.asarray(n, jnp.int32))
                         st.pos += n
                         st.chunk_left -= n
+                        spans.rows += n
+                        spans.prompt_rows += n
                         ln.index[st.sid] = st.pos
                         tick_lanes.add(ln.tag)
                         if paged:
@@ -926,6 +1005,8 @@ class DispatchCore:
                 #      dispatch per speculating lane.  Draft dispatches
                 #      see no fault injection: a wrong proposal can only
                 #      be rejected.
+                if any(ln.spec for ln in lanes):
+                    spans.phase("engine.draft")
                 for ln in lanes:
                     if not ln.spec:
                         continue
@@ -974,6 +1055,7 @@ class DispatchCore:
                 #    lane whatever the mix).  Fault injection addresses
                 #    slots by dense GLOBAL id (lane.order * S + sid) so a
                 #    single-lane engine sees byte-identical sid streams.
+                spans.phase("engine.dispatch")
                 all_ready: List[int] = []      # global ids, lane-major
                 for ln in lanes:
                     ln.active_mask = np.array(
@@ -1027,6 +1109,7 @@ class DispatchCore:
                 for ln in lanes:
                     if not ln.ready:
                         continue
+                    spans.phase("engine.dispatch")
                     tick_lanes.add(ln.tag)
                     if ln.spec:
                         # per-row verify payload: the committed next input
@@ -1046,11 +1129,14 @@ class DispatchCore:
                     else:
                         nxt, ln.cache, new_index = ln._fused(
                             ln.tokens, ln.cache, ln.index, ln.active_mask)
+                    spans.phase("engine.pull")
                     ln.nxt = np.asarray(nxt)
                     ln.index = np.array(new_index)   # writable host copy
                 if not all_ready and clock == "wall":
                     # charge chunk/prime time here
+                    spans.phase("engine.pull")
                     jax.block_until_ready([ln.cache for ln in lanes])
+                spans.phase("engine.bookkeep")
                 if fault_plan is not None and all_ready:
                     # fault: poison chosen slots' logits — modelled at the
                     # guard's observable surface, the -1 sentinel the
@@ -1137,6 +1223,8 @@ class DispatchCore:
                             _register_blocks(ln, st)
                         if st.pos < len(st.prompt):    # still prefilling
                             ln.tokens[st.sid, 0] = st.prompt[st.pos]
+                            spans.rows += 1
+                            spans.prompt_rows += 1
                             continue
                         tok = int(ln.nxt[st.sid])
                         if tok < 0:
@@ -1157,6 +1245,7 @@ class DispatchCore:
                         st.generated.append(tok)
                         gen_tokens += 1
                         emit_dispatches += 1
+                        spans.rows += 1
                         if st.first_token_s < 0:
                             st.first_token_s = now
                         if st.done():
@@ -1213,6 +1302,8 @@ class DispatchCore:
                                                                    j + 1])):
                             break
                     ln.index[st.sid] = st.pos  # the rewind past rejections
+                    spans.rows += st.pos - pos0
+                    spans.prompt_rows += st.pos - pos0 - committed
                     if committed:
                         emit_dispatches += 1
                         if ln.krow[st.sid] > 0:

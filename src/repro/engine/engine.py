@@ -163,6 +163,10 @@ class EngineReport:
         default_factory=dict)
     model_occupancy: Dict[str, List[int]] = dataclasses.field(
         default_factory=dict)        # per-tick active slots per lane
+    # XLA programs compiled (or loaded from the persistent compile cache)
+    # while serving: 0 once warmup() has compiled every shape the serve
+    # dispatches (docs/serving.md, "Profiling a serve")
+    compiles: int = 0
 
     def outputs(self) -> Dict[int, List[int]]:
         return {r.rid: r.tokens for r in self.results}
@@ -171,6 +175,53 @@ class EngineReport:
         """One model lane's outputs — what the differential harness
         compares against a dedicated single-model engine."""
         return {r.rid: r.tokens for r in self.results if r.model == model}
+
+
+@dataclasses.dataclass
+class WarmupReport:
+    """What :meth:`Engine.warmup` cost: its wall time, the XLA programs
+    it compiled or loaded from the persistent compile cache
+    (``compiles``; ``cache_hits`` of them were loaded), and the seconds
+    spent in the backend's compile step."""
+    seconds: float
+    compiles: int
+    cache_hits: int
+    compile_s: float
+
+
+class CompileCounter:
+    """Counts the XLA compiles of a stretch of the program through
+    ``jax.monitoring``: ``with CompileCounter() as cc: ...`` registers
+    its two listeners on entry and unregisters them on exit, so nothing
+    stays registered after the block.  A backend compile event is
+    recorded for every program built, and for every program loaded from
+    the persistent compile cache, which also counts a cache hit."""
+
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        self.compiles = self.cache_hits = 0
+        self.compile_s = 0.0
+
+    def _on_duration(self, event: str, secs: float, **_) -> None:
+        if event == self.COMPILE:
+            self.compiles += 1
+            self.compile_s += secs
+
+    def _on_event(self, event: str, **_) -> None:
+        if event == self.CACHE_HIT:
+            self.cache_hits += 1
+
+    def __enter__(self) -> "CompileCounter":
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
+        jax.monitoring.unregister_event_listener(self._on_event)
 
 
 class Engine:
@@ -395,12 +446,13 @@ class Engine:
                 f"(lanes: {[t for t in self.lanes]})")
         self.lanes[tag].retiring = True
 
-    def warmup(self) -> None:
+    def warmup(self) -> WarmupReport:
         """Trace + compile every lane's slot step (and, when chunked
         prefill is on, every reachable chunk bucket) on throwaway caches
         so a wall-clock ``serve`` charges its first tick to serving, not
-        to compilation."""
-        with warnings.catch_warnings():
+        to compilation.  Returns what that cost (:class:`WarmupReport`)."""
+        t0 = time.perf_counter()
+        with warnings.catch_warnings(), CompileCounter() as cc:
             warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
             S = self.num_slots
             for ln in self.lanes.values():
@@ -452,6 +504,11 @@ class Engine:
                             jnp.zeros((), jnp.int32),
                             jnp.zeros((), jnp.int32))
                         c *= 2
+                jax.block_until_ready(cache)
+        return WarmupReport(seconds=time.perf_counter() - t0,
+                            compiles=cc.compiles,
+                            cache_hits=cc.cache_hits,
+                            compile_s=cc.compile_s)
 
     def serve(self, requests: Sequence[EngineRequest], *,
               clock: str = "virtual",
@@ -567,11 +624,12 @@ class Engine:
         S = self.num_slots
 
         core = DispatchCore(self)
-        out = core.run(reqs, clock=clock, tick_s=tick_s,
-                       max_ticks=max_ticks,
-                       drop_missed_deadlines=drop_missed_deadlines,
-                       preemption=preemption, fault_plan=fault_plan,
-                       max_retries=max_retries, control=control)
+        with CompileCounter() as cc:
+            out = core.run(reqs, clock=clock, tick_s=tick_s,
+                           max_ticks=max_ticks,
+                           drop_missed_deadlines=drop_missed_deadlines,
+                           preemption=preemption, fault_plan=fault_plan,
+                           max_retries=max_retries, control=control)
         lanes = out.lanes
         # hot-swap epilogue: a retired lane that has drained leaves the
         # engine now (its device cache is released with the lane); the
@@ -685,7 +743,8 @@ class Engine:
                 t: (sum(v) / (len(v) * S) if v else 0.0)
                 for t, v in out.occ_by_lane.items()},
             model_occupancy={t: list(v)
-                             for t, v in out.occ_by_lane.items()})
+                             for t, v in out.occ_by_lane.items()},
+            compiles=cc.compiles)
 
 
 # ---------------------------------------------------------------------------

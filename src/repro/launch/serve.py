@@ -170,6 +170,12 @@ def _parse_model_quotas(pairs):
     return quotas
 
 
+def _print_warmup(name, w) -> None:
+    print(f"[warmup] {name}: {w.seconds:.2f} s, {w.compiles} programs "
+          f"compiled ({w.cache_hits} from the compile cache), "
+          f"{w.compile_s:.2f} s in backend compile")
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
@@ -476,7 +482,7 @@ def main(argv=None):
             return 1
         router = E.ReplicaRouter(fleet)
         for member in fleet:     # compile BEFORE the wall clock starts
-            member.warmup()
+            _print_warmup(member.name or "engine", member.warmup())
         rrep = router.serve(reqs, clock="wall",
                             preemption=args.preemption)
         print(f"[router] {args.replicas} replicas x {num_slots} slots "
@@ -495,8 +501,10 @@ def main(argv=None):
             print(f"[router] WARNING: {rrep.leaked_blocks} KV blocks "
                   f"leaked across the fleet")
         return 0
-    eng.warmup()         # compile before the clock starts: the measured
-    try:                                      # p99 is serving, not tracing
+    # compile before the clock starts: the measured p99 is serving, not
+    # tracing
+    _print_warmup("engine", eng.warmup())
+    try:
         rep = eng.serve(reqs, clock="wall", preemption=args.preemption,
                         fault_plan=plan)
     except E.RequestTooLong as e:
@@ -507,7 +515,8 @@ def main(argv=None):
                    for r in rep.results]) if rep.results else 0.0
     print(f"[engine] {rep.num_slots} slots x {max_seq} positions; "
           f"{len(rep.results)} requests in {rep.ticks} ticks "
-          f"({rep.wall_s:.2f} s wall)")
+          f"({rep.wall_s:.2f} s wall); {rep.compiles} programs compiled "
+          f"while serving")
     print(f"[engine] achieved p99 {rep.p99_latency_s*1e3:.2f} ms "
           f"(deadline {args.deadline_ms} ms, met {met:.1%}); "
           f"{rep.tokens_per_s:,.0f} tok/s decoded; "

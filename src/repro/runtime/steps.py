@@ -23,6 +23,12 @@ decode steps inside ONE jit boundary (one dispatch per *sequence* instead
 of one per token), with the KV cache donated so XLA updates it in place,
 and ``bucket_batch`` rounding request batches to a fixed ladder of shapes
 so the jit cache stays small and recompiles never land on the hot path.
+
+The engine's five step kinds each jit a function named after the kind —
+``slot_decode``, ``prefill_chunk``, ``prime``, ``verify``,
+``draft_propose``, single-device and sharded alike — so a profiler trace
+names every dispatch: ``PjitFunction(slot_decode)`` on the host,
+``jit_slot_decode(<hash>)`` on the device's ``XLA Modules`` line.
 """
 from __future__ import annotations
 
@@ -273,7 +279,7 @@ def make_slot_decode_step(cfg: ArchConfig, *, mode: QuantMode = FP,
         return jnp.where(active, nxt, jnp.zeros_like(nxt))
 
     if temperature > 0.0:
-        def step(params, tokens, cache, slot_index, active, rng):
+        def slot_decode(params, tokens, cache, slot_index, active, rng):
             logits, cache = _advance(params, tokens, cache, slot_index,
                                      active)
             keys = jax.vmap(lambda p: jax.random.fold_in(rng, p))(slot_index)
@@ -281,14 +287,14 @@ def make_slot_decode_step(cfg: ArchConfig, *, mode: QuantMode = FP,
             nxt = _guard(nxt, logits, active)
             return nxt, cache, slot_index + active.astype(slot_index.dtype)
     else:
-        def step(params, tokens, cache, slot_index, active):
+        def slot_decode(params, tokens, cache, slot_index, active):
             logits, cache = _advance(params, tokens, cache, slot_index,
                                      active)
             nxt = greedy_sample(logits)
             nxt = _guard(nxt, logits, active)
             return nxt, cache, slot_index + active.astype(slot_index.dtype)
 
-    return step
+    return slot_decode
 
 
 def jit_slot_decode_step(step: Callable) -> Callable:
@@ -354,14 +360,14 @@ def make_verify_step(cfg: ArchConfig, *, mode: QuantMode = FP,
         return jnp.swapaxes(samples, 0, 1), cache, idx
 
     if temperature > 0.0:
-        def step(params, tokens, cache, slot_index, n_tokens, active, rng):
+        def verify(params, tokens, cache, slot_index, n_tokens, active, rng):
             return _scan(params, tokens, cache, slot_index, n_tokens,
                          active, rng)
     else:
-        def step(params, tokens, cache, slot_index, n_tokens, active):
+        def verify(params, tokens, cache, slot_index, n_tokens, active):
             return _scan(params, tokens, cache, slot_index, n_tokens,
                          active, None)
-    return step
+    return verify
 
 
 def jit_verify_step(step: Callable) -> Callable:
@@ -388,7 +394,7 @@ def make_draft_propose_step(cfg: ArchConfig, *, mode: QuantMode = FP,
     """
     decode = make_decode_step(cfg, mode=mode)
 
-    def step(params, tokens, cache, slot_index, active):
+    def draft_propose(params, tokens, cache, slot_index, active):
         def body(carry, _):
             tok, cache, idx = carry
             logits, new_cache = decode(
@@ -405,7 +411,7 @@ def make_draft_propose_step(cfg: ArchConfig, *, mode: QuantMode = FP,
             body, (tokens, cache, slot_index), None, length=k)
         return jnp.swapaxes(props, 0, 1), cache, idx
 
-    return step
+    return draft_propose
 
 
 def jit_draft_propose_step(step: Callable) -> Callable:
@@ -462,7 +468,7 @@ def make_prefill_chunk_step(cfg: ArchConfig, *, mode: QuantMode = FP,
             (tokens, jnp.arange(chunk)))
         return slot
 
-    def step(params, tokens, cache, sid, start, n_valid):
+    def prefill_chunk(params, tokens, cache, sid, start, n_valid):
         if "block_tables" in cache:
             return _paged_step(params, tokens, cache, sid, start, n_valid)
         axes = R.cache_batch_axes(cfg, cache)
@@ -518,7 +524,7 @@ def make_prefill_chunk_step(cfg: ArchConfig, *, mode: QuantMode = FP,
                 out[k] = v.at[(slice(None),) * a + (trow,)].set(blocks)
         return out
 
-    return step
+    return prefill_chunk
 
 
 def jit_prefill_chunk_step(step: Callable) -> Callable:
@@ -549,7 +555,7 @@ def make_prime_step(cfg: ArchConfig, *, mode: QuantMode = FP) -> Callable:
     the cache.
     """
 
-    def step(params, source, cache, sid, n_valid):
+    def prime(params, source, cache, sid, n_valid):
         leaves = R.prime_slot(cfg, params, source, n_valid, mode=mode)
         axes = R.cache_batch_axes(cfg, cache)
         out = dict(cache)
@@ -558,7 +564,7 @@ def make_prime_step(cfg: ArchConfig, *, mode: QuantMode = FP) -> Callable:
                 cache[k], v.astype(cache[k].dtype), sid, axis=axes[k])
         return out
 
-    return step
+    return prime
 
 
 def jit_prime_step(step: Callable) -> Callable:
@@ -795,14 +801,14 @@ def make_sharded_slot_decode_step(cfg: ArchConfig, *, mode: QuantMode = FP,
                             out_specs=(row, out_c, row))
 
         if has_rng:
-            def outer(params, tokens, cache, slot_index, active, rng):
+            def slot_decode(params, tokens, cache, slot_index, active, rng):
                 nxt, nc, idx = fn(params, tokens, cache, slot_index,
                                   active, rng)
                 for k in paged:
                     nc[k] = _merge_shard_writes(nc[k], cache[k])
                 return nxt, nc, idx
         else:
-            def outer(params, tokens, cache, slot_index, active):
+            def slot_decode(params, tokens, cache, slot_index, active):
                 nxt, nc, idx = fn(params, tokens, cache, slot_index,
                                   active)
                 for k in paged:
@@ -819,7 +825,7 @@ def make_sharded_slot_decode_step(cfg: ArchConfig, *, mode: QuantMode = FP,
         # no donation: the paged merge reads the pre-step cache bytes,
         # so the buffer cannot be reused in place (and the non-paged
         # case keeps the same policy for one uniform compile path)
-        return jax.jit(outer, in_shardings=in_sh,
+        return jax.jit(slot_decode, in_shardings=in_sh,
                        out_shardings=(rowsh, csh_out, rowsh))
 
     memo = _StructMemo(build)
@@ -864,7 +870,7 @@ def make_sharded_verify_step(cfg: ArchConfig, *, mode: QuantMode = FP,
                             out_specs=(row, out_c, row))
 
         if has_rng:
-            def outer(params, tokens, cache, slot_index, n_tokens,
+            def verify(params, tokens, cache, slot_index, n_tokens,
                       active, rng):
                 samples, nc, idx = fn(params, tokens, cache, slot_index,
                                       n_tokens, active, rng)
@@ -872,7 +878,7 @@ def make_sharded_verify_step(cfg: ArchConfig, *, mode: QuantMode = FP,
                     nc[kk] = _merge_shard_writes(nc[kk], cache[kk])
                 return samples, nc, idx
         else:
-            def outer(params, tokens, cache, slot_index, n_tokens,
+            def verify(params, tokens, cache, slot_index, n_tokens,
                       active):
                 samples, nc, idx = fn(params, tokens, cache, slot_index,
                                       n_tokens, active)
@@ -888,7 +894,7 @@ def make_sharded_verify_step(cfg: ArchConfig, *, mode: QuantMode = FP,
                    for kk in out_c}
         in_sh = (rep, rowsh, csh_in, rowsh, rowsh, rowsh) \
             + ((rep,) if has_rng else ())
-        return jax.jit(outer, in_shardings=in_sh,
+        return jax.jit(verify, in_shardings=in_sh,
                        out_shardings=(rowsh, csh_out, rowsh))
 
     memo = _StructMemo(build)
@@ -928,7 +934,7 @@ def make_sharded_draft_propose_step(cfg: ArchConfig, *,
         fn = _shard_map(tp)(inner, in_specs=(P(), row, in_c, row, row),
                             out_specs=(row, out_c, row))
 
-        def outer(params, tokens, cache, slot_index, active):
+        def draft_propose(params, tokens, cache, slot_index, active):
             props, nc, idx = fn(params, tokens, cache, slot_index, active)
             for kk in paged:
                 nc[kk] = _merge_shard_writes(nc[kk], cache[kk])
@@ -940,7 +946,7 @@ def make_sharded_draft_propose_step(cfg: ArchConfig, *,
         csh_out = {kk: (rep if kk in paged
                         else NamedSharding(mesh, out_c[kk]))
                    for kk in out_c}
-        return jax.jit(outer,
+        return jax.jit(draft_propose,
                        in_shardings=(rep, rowsh, csh_in, rowsh, rowsh),
                        out_shardings=(rowsh, csh_out, rowsh))
 
@@ -988,7 +994,7 @@ def make_sharded_prefill_chunk_step(cfg: ArchConfig, *,
                             in_specs=(P(), P(), in_c, P(), P(), P()),
                             out_specs=out_c)
 
-        def outer(params, tokens, cache, sid, start, n_valid):
+        def prefill_chunk(params, tokens, cache, sid, start, n_valid):
             nc = fn(params, tokens, cache, sid, start, n_valid)
             if paged:
                 local_S = _global_slots(cfg_, cache, axes, paged) // tp
@@ -1004,7 +1010,7 @@ def make_sharded_prefill_chunk_step(cfg: ArchConfig, *,
         csh_out = {kk: (rep if kk in paged
                         else NamedSharding(mesh, out_c[kk]))
                    for kk in out_c}
-        return jax.jit(outer,
+        return jax.jit(prefill_chunk,
                        in_shardings=(rep, rep, csh_in, rep, rep, rep),
                        out_shardings=csh_out)
 
@@ -1059,14 +1065,14 @@ def make_sharded_prime_step(cfg: ArchConfig, *, mode: QuantMode = FP,
         fn = _shard_map(tp)(inner, in_specs=(P(), P(), in_cp, P(), P()),
                             out_specs=out_c)
 
-        def outer(params, source, cache, sid, n_valid):
+        def prime(params, source, cache, sid, n_valid):
             return fn(params, source, cache, sid, n_valid)
 
         rep, _ = _rep_and_row(tp)
         mesh = _sharded_mesh(tp)
         csh_in = {k: NamedSharding(mesh, s) for k, s in in_cp.items()}
         csh_out = {k: NamedSharding(mesh, s) for k, s in out_c.items()}
-        return jax.jit(outer,
+        return jax.jit(prime,
                        in_shardings=(rep, rep, csh_in, rep, rep),
                        out_shardings=csh_out)
 
