@@ -256,3 +256,51 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         out = _ref.flash_attention_ref(qr, kr, vr, causal=causal,
                                        window=window, out_dtype=out_dtype)
     return out.reshape(b, h, sq, hd).transpose(0, 2, 1, 3)
+
+
+# Largest state tile one grid step of the SSD state kernel moves each way.
+# Double-buffered in and out it takes four times this in VMEM, inside the
+# default scoped limit.  On a v5e at mamba2-1.3b's widths and 32 slots a
+# call read 235 us with 2 MiB tiles (64 heads), 243 us with 1 MiB and
+# 264 us with 512 KiB: fewer grid steps, less fixed cost.
+SSM_TILE_BYTES = 2 << 20
+
+
+def ssm_head_block(nh: int, hd: int, n: int) -> int:
+    """Heads per grid step of the SSD state kernel: the largest divisor of
+    ``nh`` whose float32 (hb, hd, n) tile fits ``SSM_TILE_BYTES``."""
+    fits = [d for d in range(1, nh + 1)
+            if nh % d == 0 and d * hd * n * 4 <= SSM_TILE_BYTES]
+    return max(fits, default=1)
+
+
+def ssm_state_update(h, layer, a, x, dt, bm, cm, fresh, active, *,
+                     hb: Optional[int] = None, interpret: bool = False):
+    """One SSD decode step on layer ``layer`` of the stacked state.
+
+    h (L, B, H, hd, N) float32; layer () int32; a (B, H) per-head decay;
+    x (B, H, hd); dt (B, H); bm, cm (B, N); fresh, active (B,) bool.
+    Rows with ``fresh`` start from zero state, rows without ``active``
+    keep their state bitwise.  Returns ``(h, y)`` with y (B, H, hd)
+    float32 the read-out of the updated state.
+
+    TPU (or ``interpret=True``) -> the Pallas kernel, which updates ``h``
+    in place in one pass; ``hb`` overrides the head block.  CPU -> the
+    jnp oracle.
+    """
+    a, x, dt, bm, cm = (t.astype(jnp.float32) for t in (a, x, dt, bm, cm))
+    if not (on_tpu() or interpret):
+        return _ref.ssm_state_update_ref(h, layer, a, x, dt, bm, cm,
+                                         fresh, active)
+    from repro.kernels import ssm_state as _ss
+    b, nh, hd = x.shape
+    hb = hb or ssm_head_block(nh, hd, h.shape[-1])
+
+    def head_cols(t):                  # (B, H, r) -> (B, H/hb, r, hb)
+        return t.reshape(b, nh // hb, hb, -1).swapaxes(-1, -2)
+
+    h, y = _ss.ssm_state_update(
+        h, layer, head_cols(a[..., None]), head_cols(dt[..., None] * x),
+        bm[:, None], cm[:, None], fresh, active,
+        interpret=interpret and not on_tpu())
+    return h, y.swapaxes(-1, -2).reshape(b, nh, hd)

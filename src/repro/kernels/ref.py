@@ -150,3 +150,23 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
     p = jnp.where(mask[None], p, 0.0)
     return jnp.einsum("bqk,bkd->bqd", p,
                       v.astype(jnp.float32)).astype(out_dtype)
+
+
+def ssm_state_update_ref(h: jax.Array, layer, a: jax.Array, x: jax.Array,
+                         dt: jax.Array, bm: jax.Array, cm: jax.Array,
+                         fresh: jax.Array, active: jax.Array):
+    """Oracle for the in-place SSD state update of layer ``layer``.
+
+    h (L, B, H, hd, N) float32 stacked state; a (B, H) per-head decay;
+    x (B, H, hd); dt (B, H); bm, cm (B, N); fresh, active (B,) bool.
+    Rows with ``fresh`` start from a zero state; rows without ``active``
+    keep theirs bitwise.  Returns the stacked state with layer ``layer``
+    replaced, and the read-out y (B, H, hd) of the updated (unfrozen)
+    state."""
+    hl = jax.lax.dynamic_index_in_dim(h, layer, 0, keepdims=False)
+    h_in = jnp.where(fresh[:, None, None, None], jnp.zeros_like(hl), hl)
+    contrib = jnp.einsum("bn,bhd,bh->bhdn", bm, x, dt)
+    new = a[..., None, None] * h_in + contrib
+    y = jnp.einsum("bn,bhdn->bhd", cm, new)
+    kept = jnp.where(active[:, None, None, None], new, hl)
+    return jax.lax.dynamic_update_index_in_dim(h, kept, layer, 0), y

@@ -4,7 +4,7 @@ Uniform API per family:
     init(key, cfg, dtype) -> params
     forward(params, tokens, [extra_embeds,] cfg, *, mode, remat) -> logits
     init_cache(cfg, batch, s_max, dtype) -> cache
-    decode_step(params, tokens, cache, cache_index, cfg, *, mode)
+    decode_step(params, tokens, cache, cache_index, cfg, *, mode[, active])
         -> (logits, cache)
 
 ``apply_forward`` / ``apply_decode`` normalize the extra-input plumbing
@@ -29,6 +29,10 @@ _MODULES = {
     "encdec": encdec,
     "vlm": vision,
 }
+
+
+# families whose decode state is recurrent: it freezes for inactive rows
+_RECURRENT = ("ssm", "hybrid")
 
 
 def module_for(cfg: ArchConfig):
@@ -70,10 +74,18 @@ def init_cache(cfg: ArchConfig, batch: int, s_max: int, dtype=None):
 
 
 def apply_decode(params, cfg: ArchConfig, batch: dict, cache, *,
-                 mode: QuantMode = FP):
+                 mode: QuantMode = FP, active=None):
+    """One decode step.  ``active`` (B,) bool, default every row: the
+    slot engine's freeze.  The recurrent families (ssm/hybrid) take it
+    into their decode and keep inactive rows' state bitwise; their state
+    has no ``valid_len`` frontier to hide a clobbered row behind.  The
+    other families' caches are positional (stale entries are masked on
+    read), so they are never given it and their steps do not change."""
     m = module_for(cfg)
+    kw = {} if active is None or cfg.family not in _RECURRENT \
+        else {"active": active}
     return m.decode_step(params, batch["tokens"], cache,
-                         batch["cache_index"], cfg, mode=mode)
+                         batch["cache_index"], cfg, mode=mode, **kw)
 
 
 def supports_paging(cfg: ArchConfig) -> bool:
@@ -204,20 +216,3 @@ def cache_batch_axes(cfg: ArchConfig, cache: dict) -> dict:
     if hasattr(m, "cache_batch_axes"):
         return m.cache_batch_axes(cache)
     return {k: 1 for k in cache}
-
-
-def mask_inactive_slots(cfg: ArchConfig, old_cache: dict, new_cache: dict,
-                        active):
-    """Slot-engine isolation hook: return ``new_cache`` with inactive
-    rows' *non-positional* state restored from ``old_cache``.
-
-    KV caches need nothing here — stale positional entries are invisible
-    behind each row's ``valid_len`` frontier — so the dense/moe families
-    return ``new_cache`` unchanged and pay zero extra traffic.  Recurrent
-    families (ssm/hybrid) define ``mask_inactive_slots`` in their module:
-    their state has no frontier to hide behind, so inactive rows must be
-    frozen bitwise."""
-    m = module_for(cfg)
-    if hasattr(m, "mask_inactive_slots"):
-        return m.mask_inactive_slots(old_cache, new_cache, active)
-    return new_cache

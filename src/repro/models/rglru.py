@@ -236,26 +236,6 @@ def init_cache(cfg: ArchConfig, batch: int, s_max: int,
     return cache
 
 
-def mask_inactive_slots(old: dict, new: dict, active: Array) -> dict:
-    """Freeze inactive slots' recurrent/conv state (slot engine contract).
-
-    The local-attention KV ring is positional (masked reads via
-    ``valid_len``) and needs no freeze, but the RG-LRU ``rnn_h`` and conv
-    tails are not — inactive rows' state must stay bitwise untouched.
-    ``active`` is (B,); batch axis is 2 for grouped state, 1 for leftover."""
-    out = dict(new)
-    out["rnn_h"] = jnp.where(active[None, None, :, None],
-                             new["rnn_h"], old["rnn_h"])
-    out["conv"] = jnp.where(active[None, None, :, None, None],
-                            new["conv"], old["conv"])
-    if "lo_rnn_h" in new:
-        out["lo_rnn_h"] = jnp.where(active[None, :, None],
-                                    new["lo_rnn_h"], old["lo_rnn_h"])
-        out["lo_conv"] = jnp.where(active[None, :, None, None],
-                                   new["lo_conv"], old["lo_conv"])
-    return out
-
-
 def cache_batch_axes(cache: dict) -> dict:
     """Batch axis per cache leaf (the slot engine slices slots with it).
     Grouped recurrent state stacks (group, block) ahead of batch."""
@@ -267,14 +247,20 @@ def cache_batch_axes(cache: dict) -> dict:
 
 
 def decode_step(params: dict, tokens: Array, cache: dict, cache_index: Array,
-                cfg: ArchConfig, *, mode: QuantMode = FP
-                ) -> Tuple[Array, dict]:
+                cfg: ArchConfig, *, mode: QuantMode = FP,
+                active: Array = None) -> Tuple[Array, dict]:
     """One-token decode.  ``cache_index`` is scalar () (lockstep batch) or
     (B,) per-row for the slot engine: RoPE positions, ring write indices
     and window masks all become per-row, and — like the SSM — a row at
     position 0 has its recurrent/conv state zeroed before the update (the
-    reset-at-zero scrub that makes slot reuse safe)."""
+    reset-at-zero scrub that makes slot reuse safe).
+
+    ``active`` (B,) bool, default every row: rows outside it keep their
+    RG-LRU ``rnn_h`` and conv tails bitwise (the slot engine's freeze).
+    The local-attention KV ring is positional (reads masked by
+    ``valid_len``) and needs no freeze."""
     b, s = tokens.shape
+    old = cache
     x = L.embed(params["embed"], tokens)
     ci = jnp.asarray(cache_index)
     if ci.ndim:                             # (B,): per-slot positions
@@ -331,6 +317,15 @@ def decode_step(params: dict, tokens: Array, cache: dict, cache_index: Array,
             (params["leftover"], cache["lo_rnn_h"], cache["lo_conv"]))
         new_cache["lo_rnn_h"] = lh
         new_cache["lo_conv"] = lc
+
+    if active is not None:
+        keep = {"rnn_h": active[None, None, :, None],
+                "conv": active[None, None, :, None, None],
+                "lo_rnn_h": active[None, :, None],
+                "lo_conv": active[None, :, None, None]}
+        for k, row in keep.items():
+            if k in new_cache:
+                new_cache[k] = jnp.where(row, new_cache[k], old[k])
 
     x = L.rmsnorm(params["ln_f"], x)
     return L.unembed(params["embed"], x), new_cache

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.core.qlinear import FP, QuantMode, init_linear, linear
+from repro.kernels import ops
 from repro.models import layers as L
 from repro.runtime.sharding import constrain
 
@@ -138,11 +139,11 @@ def _ssd_chunked(xh: Array, dt: Array, a_log: Array, Bm: Array, Cm: Array,
     return y[:, :s]
 
 
-def ssd_layer(p: dict, x: Array, cfg: ArchConfig, *, mode: QuantMode = FP,
-              state: dict = None) -> Tuple[Array, dict]:
-    """One Mamba-2 block.  state=None -> chunked full-sequence;
-    state={'h','conv'} -> single-step decode."""
-    b, s, d = x.shape
+def _mix_in(p: dict, x: Array, cfg: ArchConfig, mode: QuantMode,
+            conv_state: Array = None):
+    """Norm, in-projection and causal conv of one block.  Returns
+    (xh (B,S,H,hd), z, Bm, Cm, dt (B,S,H) float32, new conv tail)."""
+    b, s, _ = x.shape
     din, n, nh, hd = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_headdim
     h_in = L.rmsnorm(p["norm"], x)
     proj = linear(p["in_proj"], h_in, mode=mode)
@@ -152,34 +153,50 @@ def ssd_layer(p: dict, x: Array, cfg: ArchConfig, *, mode: QuantMode = FP,
                          + p["dt_bias"][None, None])       # (B,S,H)
 
     conv_in = jnp.concatenate([xz, Bm, Cm], axis=-1)
-    conv_state = None if state is None else state["conv"]
     conv_out, new_conv = _causal_conv(conv_in, p["conv_w"], p["conv_b"],
                                       conv_state)
     xz, Bm, Cm = jnp.split(conv_out, [din, din + n], axis=-1)
-    xh = xz.reshape(b, s, nh, hd)
+    return xz.reshape(b, s, nh, hd), z, Bm, Cm, dt, new_conv
 
-    if state is None:
-        y = _ssd_chunked(xh, dt, p["a_log"], Bm, Cm, cfg.ssm_chunk)
-        new_h = None
-    else:
-        # O(1) decode: h' = a h + dt B x ; y = C.h
-        hst = state["h"]                                   # (B,H,hd,N)
-        a_step = jnp.exp(dt[:, 0].astype(jnp.float32)
-                         * (-jnp.exp(p["a_log"]))[None])   # (B,H)
-        contrib = jnp.einsum("bn,bhd,bh->bhdn", Bm[:, 0].astype(jnp.float32),
-                             xh[:, 0].astype(jnp.float32),
-                             dt[:, 0].astype(jnp.float32))
-        new_h = a_step[..., None, None] * hst + contrib
-        new_h = constrain(new_h, "ssm_state")
-        y = jnp.einsum("bn,bhdn->bhd", Cm[:, 0].astype(jnp.float32),
-                       new_h)[:, None].reshape(b, 1, nh, hd)
 
+def _mix_out(p: dict, x: Array, y: Array, xh: Array, z: Array,
+             mode: QuantMode) -> Array:
+    """Skip term, gate and out-projection; residual added."""
+    b, s, nh, hd = xh.shape
     y = y + p["D"][None, None, :, None] * xh.astype(jnp.float32)
-    y = (y.reshape(b, s, din) * jax.nn.silu(z.astype(jnp.float32))
+    y = (y.reshape(b, s, nh * hd) * jax.nn.silu(z.astype(jnp.float32))
          ).astype(x.dtype)
     out = linear(p["out_proj"], y, mode=mode)
-    new_state = None if state is None else {"h": new_h, "conv": new_conv}
-    return x + constrain(out, "act"), new_state
+    return x + constrain(out, "act")
+
+
+def ssd_layer(p: dict, x: Array, cfg: ArchConfig, *,
+              mode: QuantMode = FP) -> Array:
+    """One Mamba-2 block over a full sequence (chunked SSD)."""
+    xh, z, Bm, Cm, dt, _ = _mix_in(p, x, cfg, mode)
+    y = _ssd_chunked(xh, dt, p["a_log"], Bm, Cm, cfg.ssm_chunk)
+    return _mix_out(p, x, y, xh, z, mode)
+
+
+def ssd_decode_layer(p: dict, x: Array, h: Array, conv: Array, layer: Array,
+                     fresh: Array, active: Array, cfg: ArchConfig, *,
+                     mode: QuantMode = FP) -> Tuple[Array, Array, Array]:
+    """One Mamba-2 block, one token, on layer ``layer`` of the stacked
+    state: h (L, B, H, hd, N) float32 and conv (L, B, width-1, C).
+
+    O(1) decode: h' = a h + dt B x, y = C.h'.  Rows with ``fresh`` start
+    from zero state and rows without ``active`` keep theirs bitwise; both
+    happen inside ``ops.ssm_state_update``, which on a TPU rewrites the
+    layer's state in place in one pass.  Returns (x, h, conv)."""
+    conv_l = jax.lax.dynamic_index_in_dim(conv, layer, 0, keepdims=False)
+    conv_in = jnp.where(fresh[:, None, None], jnp.zeros_like(conv_l), conv_l)
+    xh, z, Bm, Cm, dt, new_conv = _mix_in(p, x, cfg, mode, conv_in)
+    new_conv = jnp.where(active[:, None, None], new_conv, conv_l)
+    conv = jax.lax.dynamic_update_index_in_dim(conv, new_conv, layer, 0)
+    a_step = jnp.exp(dt[:, 0] * (-jnp.exp(p["a_log"]))[None])   # (B,H)
+    h, y = ops.ssm_state_update(h, layer, a_step, xh[:, 0], dt[:, 0],
+                                Bm[:, 0], Cm[:, 0], fresh, active)
+    return _mix_out(p, x, y[:, None], xh, z, mode), h, conv
 
 
 # ---------------------------------------------------------------------------
@@ -202,8 +219,7 @@ def forward(params: dict, tokens: Array, cfg: ArchConfig, *,
     x = L.embed(params["embed"], tokens)
 
     def body(x, lp):
-        out, _ = ssd_layer(lp, x, cfg, mode=mode)
-        return out, None
+        return ssd_layer(lp, x, cfg, mode=mode), None
 
     if remat:
         body = jax.checkpoint(
@@ -225,46 +241,35 @@ def init_cache(cfg: ArchConfig, batch: int, s_max: int,
     }
 
 
-def mask_inactive_slots(old: dict, new: dict, active: Array) -> dict:
-    """Freeze inactive slots' recurrent state (slot engine contract).
-
-    Unlike a KV cache, the SSM state is NOT positional: there is no
-    ``valid_len`` mask at read time that could hide a clobbered ``h`` or
-    conv tail, so the fused slot step must leave inactive rows' state
-    bitwise untouched.  ``active`` is (B,); state batch axis is 1."""
-    return {
-        "h": jnp.where(active[None, :, None, None, None],
-                       new["h"], old["h"]),
-        "conv": jnp.where(active[None, :, None, None],
-                          new["conv"], old["conv"]),
-    }
-
-
 def decode_step(params: dict, tokens: Array, cache: dict, cache_index: Array,
-                cfg: ArchConfig, *, mode: QuantMode = FP
-                ) -> Tuple[Array, dict]:
+                cfg: ArchConfig, *, mode: QuantMode = FP,
+                active: Array = None) -> Tuple[Array, dict]:
     """One-token decode.  ``cache_index`` is scalar () (lockstep batch) or
     (B,) per-row for the slot engine.  The state is position-free, so the
     index's only job here is the *reset-at-zero scrub*: a row decoding its
     position-0 token by definition has no history, so its carried
     ``h``/conv state is zeroed before the update — that is what makes a
-    reused slot's previous tenant invisible without scrubbing the pool."""
+    reused slot's previous tenant invisible without scrubbing the pool.
+
+    ``active`` (B,) bool, default every row: rows outside it keep their
+    ``h`` and conv state bitwise (the slot engine's freeze).  Unlike a KV
+    cache the state has no ``valid_len`` frontier to hide a clobbered row
+    behind, so the freeze is part of the update.  Their logits are
+    computed and discarded by the caller."""
     b, s = tokens.shape
     x = L.embed(params["embed"], tokens)
-    ci = jnp.asarray(cache_index)
-    fresh = jnp.broadcast_to(ci == 0, (b,))
-    h_in = jnp.where(fresh[None, :, None, None, None],
-                     jnp.zeros_like(cache["h"]), cache["h"])
-    conv_in = jnp.where(fresh[None, :, None, None],
-                        jnp.zeros_like(cache["conv"]), cache["conv"])
+    fresh = jnp.broadcast_to(jnp.asarray(cache_index) == 0, (b,))
+    active = (jnp.ones((b,), bool) if active is None
+              else jnp.asarray(active, bool))
 
-    def body(x, lp_and_state):
-        lp, h, conv = lp_and_state
-        out, new_state = ssd_layer(lp, x, cfg, mode=mode,
-                                   state={"h": h, "conv": conv})
-        return out, (new_state["h"], new_state["conv"])
+    def body(carry, inp):
+        x, h, conv = carry
+        lp, layer = inp
+        return ssd_decode_layer(lp, x, h, conv, layer, fresh, active, cfg,
+                                mode=mode), None
 
-    x, (new_h, new_conv) = jax.lax.scan(
-        body, x, (params["layers"], h_in, conv_in))
+    (x, h, conv), _ = jax.lax.scan(
+        body, (x, cache["h"], cache["conv"]),
+        (params["layers"], jnp.arange(cfg.n_layers)))
     x = L.rmsnorm(params["ln_f"], x)
-    return L.unembed(params["embed"], x), {"h": new_h, "conv": new_conv}
+    return L.unembed(params["embed"], x), {"h": h, "conv": conv}

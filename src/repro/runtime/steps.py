@@ -143,10 +143,12 @@ def make_prefill_step(cfg: ArchConfig, *, mode: QuantMode = FP) -> Callable:
 
 
 def make_decode_step(cfg: ArchConfig, *, mode: QuantMode = FP) -> Callable:
-    def decode_step(params, batch, cache):
-        logits, new_cache = R.apply_decode(params, cfg, batch, cache,
-                                           mode=mode)
-        return logits, new_cache
+    """``decode_step(params, batch, cache, active=None) -> (logits,
+    cache)``; ``active`` (B,) bool freezes the other rows' recurrent
+    state (``registry.apply_decode``)."""
+    def decode_step(params, batch, cache, active=None):
+        return R.apply_decode(params, cfg, batch, cache, mode=mode,
+                              active=active)
     return decode_step
 
 
@@ -247,23 +249,22 @@ def make_slot_decode_step(cfg: ArchConfig, *, mode: QuantMode = FP,
     :func:`make_decode_loop`'s key schedule, so engine sampling is
     parity-testable against the fused loop and the per-token reference.
 
-    The active mask folds into sampling (inactive rows emit 0) and into
-    the index advance (inactive rows freeze).  Works for every registry
-    family with token-only decode (dense/moe/ssm/hybrid): positional KV
-    writes are row-local scatters at each slot's own frontier with reads
-    masked by ``slot_index``, while non-positional recurrent state is
-    frozen for inactive rows through ``registry.mask_inactive_slots`` and
-    scrubbed on slot reuse by the families' reset-at-position-0 rule (the
-    engine's isolation property test poisons dead rows to prove both).
+    The active mask folds into sampling (inactive rows emit 0), into
+    the index advance (inactive rows freeze) and into the decode itself.
+    Works for every registry family with token-only decode
+    (dense/moe/ssm/hybrid): positional KV writes are row-local scatters at
+    each slot's own frontier with reads masked by ``slot_index``, while
+    the recurrent families (ssm/hybrid) freeze inactive rows' state and
+    scrub a reused slot's by the reset-at-position-0 rule inside their
+    own decode, in the same pass that updates it (the engine's isolation
+    property test poisons dead rows to prove both).
     Wrap with :func:`jit_slot_decode_step` to donate the cache.
     """
     decode = make_decode_step(cfg, mode=mode)
 
     def _advance(params, tokens, cache, slot_index, active):
-        logits, new_cache = decode(
-            params, {"tokens": tokens, "cache_index": slot_index}, cache)
-        new_cache = R.mask_inactive_slots(cfg, cache, new_cache, active)
-        return logits, new_cache
+        return decode(params, {"tokens": tokens, "cache_index": slot_index},
+                      cache, active)
 
     def _guard(nxt, logits, active):
         # In-graph finite guard: a row whose last-position logits contain
@@ -341,8 +342,8 @@ def make_verify_step(cfg: ArchConfig, *, mode: QuantMode = FP,
             tok, j = inp                        # tok (S,), j ()
             act = active & (j < n_tokens)
             logits, new_cache = decode(
-                params, {"tokens": tok[:, None], "cache_index": idx}, cache)
-            new_cache = R.mask_inactive_slots(cfg, cache, new_cache, act)
+                params, {"tokens": tok[:, None], "cache_index": idx}, cache,
+                act)
             if temperature > 0.0:
                 keys = jax.vmap(lambda p: jax.random.fold_in(rng, p))(idx)
                 nxt = temperature_sample_rows(logits, keys, temperature)
@@ -398,8 +399,7 @@ def make_draft_propose_step(cfg: ArchConfig, *, mode: QuantMode = FP,
         def body(carry, _):
             tok, cache, idx = carry
             logits, new_cache = decode(
-                params, {"tokens": tok, "cache_index": idx}, cache)
-            new_cache = R.mask_inactive_slots(cfg, cache, new_cache, active)
+                params, {"tokens": tok, "cache_index": idx}, cache, active)
             nxt = greedy_sample(logits)
             finite = jnp.all(
                 jnp.isfinite(logits[:, -1].astype(jnp.float32)), axis=-1)

@@ -189,3 +189,43 @@ def test_flash_attention_rows_sum_property(seed):
     out = ops.flash_attention(q, k, v, causal=True, interpret=True,
                               out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(out), 1.0, rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# SSD state update kernel
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("nh,hd,n,hb,layer", [
+    (64, 64, 128, None, 0),    # mamba2-1.3b per-layer widths, default block
+    (64, 64, 128, 16, 2),
+    (64, 64, 128, 32, 1),
+    (8, 32, 16, None, 1),      # the reduced config's widths
+])
+def test_ssm_state_update_matches_ref(nh, hd, n, hb, layer):
+    """One layer of the stacked state updated in place: active rows and
+    the read-out match the oracle; inactive rows' poison and every other
+    layer come back bitwise."""
+    n_layers, b = 3, 4
+    keys = jax.random.split(jax.random.PRNGKey(nh + layer), 6)
+    h = _rand(keys[0], (n_layers, b, nh, hd, n))
+    h = h.at[:, 1].set(107.0).at[:, 3].set(-9.0)
+    a = jax.random.uniform(keys[1], (b, nh))
+    x = _rand(keys[2], (b, nh, hd))
+    dt = jax.nn.softplus(_rand(keys[3], (b, nh)))
+    bm, cm = _rand(keys[4], (b, n)), _rand(keys[5], (b, n))
+    fresh = jnp.array([True, False, False, True])
+    active = jnp.array([True, False, True, False])
+    h_np = np.asarray(h)
+    want_h, want_y = ref.ssm_state_update_ref(h, layer, a, x, dt, bm, cm,
+                                              fresh, active)
+    got_h, got_y = ops.ssm_state_update(h, jnp.int32(layer), a, x, dt, bm,
+                                        cm, fresh, active, hb=hb,
+                                        interpret=True)
+    got_h, want_h = np.asarray(got_h), np.asarray(want_h)
+    others = [i for i in range(n_layers) if i != layer]
+    np.testing.assert_array_equal(got_h[others], h_np[others])
+    np.testing.assert_array_equal(got_h[layer, [1, 3]], h_np[layer, [1, 3]])
+    for got, want in ((got_h[layer, [0, 2]], want_h[layer, [0, 2]]),
+                      (np.asarray(got_y), np.asarray(want_y))):
+        np.testing.assert_allclose(got, want, rtol=1e-5,
+                                   atol=1e-5 * np.abs(want).max())

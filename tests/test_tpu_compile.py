@@ -141,3 +141,40 @@ def test_int8_kv_slot_step_compiles_and_fits(one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_ssm_slot_step_updates_state_in_place(one_chip, monkeypatch):
+    """The mamba2-1.3b slot step at published widths (32 slots, W8A16,
+    depth cut to 4): the state kernel is in it, the float32 state is
+    updated in the donated buffer, and no temporary holds as much as one
+    layer's state."""
+    from repro.core.qlinear import W8A16
+    from repro.kernels import ops
+    from repro.models import registry as R
+    from repro.runtime import steps as ST
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("mamba2-1.3b"), n_layers=4)
+    slots = 32
+    put = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = put(jax.eval_shape(lambda k: R.init_quantized(k, cfg),
+                                jax.random.PRNGKey(0)))
+    cache = put(jax.eval_shape(lambda: R.init_cache(cfg, slots, 32)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    step = jax.jit(ST.make_slot_decode_step(cfg, mode=W8A16),
+                   donate_argnums=(2,))
+    compiled = step.lower(params, i32(slots, 1), cache, i32(slots),
+                          jax.ShapeDtypeStruct((slots,), bool,
+                                               sharding=one_chip)).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and "%ssm_state_update" in ln]
+    assert calls, "the state kernel is not in the compiled step"
+    nbytes = lambda x: x.size * x.dtype.itemsize
+    layer_state = nbytes(cache["h"]) // cfg.n_layers        # 67 MB
+    cache_bytes = sum(nbytes(x) for x in cache.values())
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_state
+    assert mem.alias_size_in_bytes >= cache_bytes
