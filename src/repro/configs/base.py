@@ -14,7 +14,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-FAMILIES = ("dense", "moe", "ssm", "hybrid", "encdec", "vlm")
+FAMILIES = ("dense", "moe", "ssm", "hybrid", "hybrid_moe", "encdec", "vlm")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,16 +56,33 @@ class ArchConfig:
     top_k: int = 0
     n_shared_experts: int = 0
     capacity_factor: float = 1.25
+    shared_ff: int = 0               # shared-expert width; 0 -> d_ff * n_shared
+    # this chip's share of the routed experts under expert parallelism:
+    # experts [expert_offset, expert_offset + experts_held); 0 -> all
+    experts_held: int = 0
+    expert_offset: int = 0
     # SSM (mamba2)
     ssm_state: int = 0
     ssm_headdim: int = 64
     ssm_expand: int = 2
     ssm_chunk: int = 256
     conv_width: int = 4
+    ssm_gated_norm: bool = False     # rmsnorm(y * silu(z)) before out_proj
     # hybrid (recurrentgemma): block pattern unit, e.g. ("rec","rec","attn")
     block_pattern: Tuple[str, ...] = ()
     local_window: int = 2048
     rnn_width: int = 0
+    # hybrid_moe (granite-4.0-h): the mixer of every layer, "mamba" or
+    # "attention"; each layer ends in the routed + shared MoE block
+    layer_types: Tuple[str, ...] = ()
+    # scalar multipliers (granite): embeddings, both residual branches,
+    # attention scores (0 -> head_dim ** -0.5), and a divisor of logits
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
+    use_rope: bool = True
+    norm_eps: float = 1e-6
     # encdec (whisper)
     n_enc_layers: int = 0
     enc_seq: int = 1500              # stubbed audio-frame embeddings
@@ -97,6 +114,15 @@ class ArchConfig:
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_headdim
 
+    @property
+    def shared_width(self) -> int:
+        return self.shared_ff or self.d_ff * self.n_shared_experts
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
+
     def param_count(self) -> int:
         """Total parameters N (embedding included once when tied)."""
         d, f, v = self.d_model, self.d_ff, self.vocab
@@ -114,6 +140,15 @@ class ArchConfig:
             e_ff = d * f * (3 if self.gated_mlp else 2)
             per_layer = (attn + self.n_experts * e_ff
                          + self.n_shared_experts * e_ff + d * self.n_experts)
+        elif self.family == "hybrid_moe":
+            din, n, nh = self.d_inner, self.ssm_state, self.ssm_heads
+            mamba = (d * (2 * din + 2 * n + nh) + self.conv_width
+                     * (din + 2 * n) + din * d + 3 * nh + 2 * din)
+            n_m = self.layer_types.count("mamba")
+            moe = (3 * d * f * self.n_experts + 3 * d * self.shared_width
+                   + d * self.n_experts)
+            return int(emb + n_m * mamba + (self.n_layers - n_m) * attn
+                       + self.n_layers * moe)
         elif self.family == "hybrid":
             pat = self.block_pattern or ("rec",)
             rnn = self.rnn_width or d
@@ -138,7 +173,7 @@ class ArchConfig:
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: top_k + shared experts only)."""
-        if self.family != "moe":
+        if self.family not in ("moe", "hybrid_moe"):
             return self.param_count()
         d, f = self.d_model, self.d_ff
         e_ff = d * f * (3 if self.gated_mlp else 2)
@@ -211,15 +246,29 @@ class ArchConfig:
         kv = max(1, min(self.n_kv_heads, 2))
         h = max(kv, shrink_heads(self.n_heads))
         h = (h // kv) * kv or kv
+        n_layers = min(self.n_layers, 2 * max(1, len(self.block_pattern)
+                                              or 1))
+        layer_types = self.layer_types
+        if layer_types:
+            # two periods, each run of one mixer kind cut to one layer
+            period = layer_period(layer_types)
+            runs = tuple(t for i, t in enumerate(period)
+                         if i == 0 or period[i - 1] != t)
+            layer_types = runs * 2
+            n_layers = len(layer_types)
+        n_experts = min(self.n_experts, 4) if self.n_experts else 0
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",
-            n_layers=min(self.n_layers, 2 * max(1, len(self.block_pattern)
-                                                or 1)),
+            n_layers=n_layers, layer_types=layer_types,
             d_model=128, n_heads=h, n_kv_heads=kv,
             d_ff=256, vocab=512, head_dim=32,
-            n_experts=min(self.n_experts, 4) if self.n_experts else 0,
+            n_experts=n_experts,
             top_k=min(self.top_k, 2) if self.top_k else 0,
+            experts_held=min(self.experts_held, n_experts // 2)
+            if self.experts_held else 0,
+            expert_offset=0,
+            shared_ff=256 if self.shared_ff else 0,
             # drop-free capacity so decode == teacher-forced forward in tests
             capacity_factor=2.0 if self.n_experts else self.capacity_factor,
             n_shared_experts=min(self.n_shared_experts, 1)
@@ -235,6 +284,15 @@ class ArchConfig:
             local_window=32,
             window=min(self.window, 64) if self.window else None,
         )
+
+
+def layer_period(layer_types: Tuple[str, ...]) -> Tuple[str, ...]:
+    """The shortest prefix of ``layer_types`` that repeats to the whole."""
+    n = len(layer_types)
+    for p in range(1, n + 1):
+        if n % p == 0 and layer_types == layer_types[:p] * (n // p):
+            return layer_types[:p]
+    return layer_types
 
 
 _REGISTRY: Dict[str, ArchConfig] = {}

@@ -182,11 +182,15 @@ class WarmupReport:
     """What :meth:`Engine.warmup` cost: its wall time, the XLA programs
     it compiled or loaded from the persistent compile cache
     (``compiles``; ``cache_hits`` of them were loaded), and the seconds
-    spent in the backend's compile step."""
+    spent in the backend's compile step.  ``cache_bytes`` gives each
+    lane's decode cache in bytes by kind (``registry.cache_bytes``: SSD
+    or RG-LRU state, conv tails, K/V, ...)."""
     seconds: float
     compiles: int
     cache_hits: int
     compile_s: float
+    cache_bytes: Dict[str, Dict[str, int]] = dataclasses.field(
+        default_factory=dict)
 
 
 class CompileCounter:
@@ -455,8 +459,10 @@ class Engine:
         with warnings.catch_warnings(), CompileCounter() as cc:
             warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
             S = self.num_slots
-            for ln in self.lanes.values():
+            cache_bytes = {}
+            for tag, ln in self.lanes.items():
                 cache = ln._init_cache()
+                cache_bytes[tag] = R.cache_bytes(cache)
                 if ln._prime_step is not None:
                     cache = ln._prime_step(
                         ln.params,
@@ -508,7 +514,8 @@ class Engine:
         return WarmupReport(seconds=time.perf_counter() - t0,
                             compiles=cc.compiles,
                             cache_hits=cc.cache_hits,
-                            compile_s=cc.compile_s)
+                            compile_s=cc.compile_s,
+                            cache_bytes=cache_bytes)
 
     def serve(self, requests: Sequence[EngineRequest], *,
               clock: str = "virtual",

@@ -228,10 +228,11 @@ def decode_attention(q, k, v, k_scale, v_scale, valid_len, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     blk_q: int = 128, blk_k: int = 128,
-                    out_dtype=None, interpret: bool = False):
+                    out_dtype=None, sm_scale=None, interpret: bool = False):
     """Fused flash attention.  q: (B, Sq, H, hd); k, v: (B, Skv, H, hd)
-    (KV already expanded to H heads).  TPU -> Pallas kernel; CPU -> dense
-    oracle unless ``interpret=True`` (kernel body under the interpreter).
+    (KV already expanded to H heads); scores scaled by ``sm_scale``,
+    default hd ** -0.5.  TPU -> Pallas kernel; CPU -> dense oracle unless
+    ``interpret=True`` (kernel body under the interpreter).
     """
     from repro.kernels import flash_attention as _fa
     b, sq, h, hd = q.shape
@@ -249,12 +250,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         vp = _pad_to(_pad_to(vr, bk, 1), 128, 2)
         out = _fa.flash_attention_bhsd(
             qp, kp, vp, blk_q=bq, blk_k=bk, causal=causal, window=window,
-            kv_len=skv, sm_scale=hd ** -0.5, out_dtype=out_dtype,
+            kv_len=skv, sm_scale=hd ** -0.5 if sm_scale is None else sm_scale,
+            out_dtype=out_dtype,
             interpret=interpret and not on_tpu())
         out = out[:, :sq, :hd]
     else:
         out = _ref.flash_attention_ref(qr, kr, vr, causal=causal,
-                                       window=window, out_dtype=out_dtype)
+                                       window=window, sm_scale=sm_scale,
+                                       out_dtype=out_dtype)
     return out.reshape(b, h, sq, hd).transpose(0, 2, 1, 3)
 
 
@@ -304,3 +307,44 @@ def ssm_state_update(h, layer, a, x, dt, bm, cm, fresh, active, *,
         bm[:, None], cm[:, None], fresh, active,
         interpret=interpret and not on_tpu())
     return h, y.swapaxes(-1, -2).reshape(b, nh, hd)
+
+
+def _lane_block(dim: int, cap: int = 1024) -> int:
+    """The largest multiple of 128 that divides ``dim`` and is at most
+    ``cap``; ``dim`` itself where it is not a multiple of 128."""
+    if dim % 128:
+        return dim
+    return max(b for b in range(128, min(dim, cap) + 1, 128)
+               if dim % b == 0)
+
+
+def moe_w8a16(x, w: QTensor, layer, counts, *,
+              out_dtype=jnp.float32, interpret: bool = False):
+    """Every held expert's int8 weights times its rows, W8A16.
+
+    ``x`` (M, K) rows every expert reads, or (E, M, K) one set per
+    expert; ``w`` a QTensor of (L, E, K, N) int8 values with
+    (L, E, 1, N) column scales, stacked over layers, and ``layer`` the
+    one to read; ``counts`` (E,) rows routed to each expert, an expert
+    with none gives zeros.  Returns (E, M, N) in ``out_dtype``.
+
+    TPU (or ``interpret=True``) -> the Pallas kernel
+    (``kernels/moe_w8a16.py``), which reads each expert's int8 weights
+    and scales once and converts them in VMEM; CPU -> the jnp oracle.
+    """
+    values, scale = w.values, w.scale
+    k, n = values.shape[2:]
+    m = x.shape[-2]
+    counts = jnp.asarray(counts, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
+    if not (on_tpu() or interpret):
+        return _ref.moe_w8a16_ref(x, values, scale, layer, counts,
+                                  out_dtype=out_dtype)
+    from repro.kernels import moe_w8a16 as _moe
+    bk, bn = _lane_block(k), _lane_block(n)
+    bm = min(256, -(-m // 16) * 16)             # bf16 rows pack 16 a tile
+    xp = _pad_to(x.astype(jnp.bfloat16), bm, x.ndim - 2)
+    out = _moe.moe_w8a16(xp, values, scale, layer, counts, bm=bm, bn=bn,
+                         bk=bk, out_dtype=out_dtype,
+                         interpret=interpret and not on_tpu())
+    return out[:, :m]

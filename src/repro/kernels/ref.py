@@ -130,13 +130,15 @@ def decode_attention_paged_ref(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, window=None,
-                        kv_len=None, out_dtype=jnp.bfloat16) -> jax.Array:
+                        kv_len=None, sm_scale=None,
+                        out_dtype=jnp.bfloat16) -> jax.Array:
     """Dense softmax attention oracle.  q: (BH, Sq, hd); k,v: (BH, Skv, hd)."""
     bh, sq, hd = q.shape
     skv = k.shape[1]
     kv_len = skv if kv_len is None else kv_len
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * hd ** -0.5
+                   k.astype(jnp.float32)) * (
+                       hd ** -0.5 if sm_scale is None else sm_scale)
     qpos = jnp.arange(sq)[:, None]
     kpos = jnp.arange(skv)[None, :]
     mask = kpos < kv_len
@@ -170,3 +172,22 @@ def ssm_state_update_ref(h: jax.Array, layer, a: jax.Array, x: jax.Array,
     y = jnp.einsum("bn,bhdn->bhd", cm, new)
     kept = jnp.where(active[:, None, None, None], new, hl)
     return jax.lax.dynamic_update_index_in_dim(h, kept, layer, 0), y
+
+
+def moe_w8a16_ref(x: jax.Array, w: jax.Array, scale: jax.Array, layer,
+                  counts: jax.Array, *, out_dtype=jnp.float32) -> jax.Array:
+    """Oracle for the held-expert grouped matmul of layer ``layer``.
+
+    x (M, K) rows shared by every expert, or (E, M, K) one set per
+    expert (the kernel reads them as bf16); w (L, E, K, N) int8; scale
+    (L, E, 1, N);
+    counts (E,) rows routed to each expert.  Returns (E, M, N):
+    ``x_e @ (w[l, e] * scale[l, e])``, zero for an expert with no rows."""
+    wl = jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+    sl = jax.lax.dynamic_index_in_dim(scale, layer, 0, keepdims=False)
+    xf = x.astype(jnp.float32)
+    spec = "mk,ekn->emn" if x.ndim == 2 else "emk,ekn->emn"
+    acc = jnp.einsum(spec, xf, wl.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    out = jnp.where(counts[:, None, None] > 0, acc * sl, 0.0)
+    return out.astype(out_dtype)

@@ -96,6 +96,7 @@ class AttnConfig:
     causal: bool = True
     use_rope: bool = True
     q_block: int = 512               # chunked-attention query block
+    sm_scale: Optional[float] = None  # score scale; None -> head_dim ** -0.5
 
 
 def init_attention(key, cfg: AttnConfig, dtype=jnp.float32) -> dict:
@@ -161,7 +162,8 @@ def paged_append(c: Array, new: Array, block_tables: Array, idx: Array, *,
 def _chunked_attention(q: Array, k: Array, v: Array, *, causal: bool,
                        window: Optional[int], q_block: int,
                        q_offset: int = 0,
-                       kv_valid_len: Optional[Array] = None) -> Array:
+                       kv_valid_len: Optional[Array] = None,
+                       sm_scale: Optional[float] = None) -> Array:
     """Memory-bounded attention: scan over query blocks, masked scores.
 
     q: (B, Sq, H, hd); k, v: (B, Sk, H, hd).  Keeps the live score tensor at
@@ -177,7 +179,7 @@ def _chunked_attention(q: Array, k: Array, v: Array, *, causal: bool,
     """
     b, sq, h, hd = q.shape
     sk = k.shape[1]
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if sm_scale is None else sm_scale
     blk = min(q_block, sq)
     pad = (-sq) % blk
     if pad:
@@ -401,7 +403,7 @@ def attention(p: dict, x: Array, cfg: AttnConfig, *,
             out = linear(p["wo"], out.reshape(b, s, h * hd), mode=mode)
             return constrain(out, "act"), new_cache
         q5 = q.reshape(b, s, kvh, g, hd)
-        scale = hd ** -0.5
+        scale = hd ** -0.5 if cfg.sm_scale is None else cfg.sm_scale
         # bf16-native contractions with f32 accumulate; per-token dequant
         # scales are independent of the contracted hd axis, so they fold
         # into the scores/probs instead of materializing a dequantized
@@ -456,13 +458,14 @@ def attention(p: dict, x: Array, cfg: AttnConfig, *,
             # kernel carries no per-row KV frontier, so a primed source
             # with per-row valid lengths takes the masked chunked path.
             out = kops.flash_attention(q, kfull, vfull, causal=causal,
-                                       window=window)
+                                       window=window, sm_scale=cfg.sm_scale)
         else:
             # pure-JAX chunked path: identical math (tests assert so),
             # used on CPU and in the dry-run.
             out = _chunked_attention(q, kfull, vfull, causal=causal,
                                      window=window, q_block=cfg.q_block,
-                                     kv_valid_len=xattn_valid_len)
+                                     kv_valid_len=xattn_valid_len,
+                                     sm_scale=cfg.sm_scale)
     out = constrain(out, "act_heads")
     out = linear(p["wo"], out.reshape(b, s, h * hd), mode=mode)
     return constrain(out, "act"), new_cache

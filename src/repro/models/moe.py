@@ -158,6 +158,113 @@ def aux_load_balance_loss(p: dict, x: Array, cfg: ArchConfig) -> Array:
 
 
 # ---------------------------------------------------------------------------
+# expert share: the routed experts this chip holds, dropless
+# ---------------------------------------------------------------------------
+
+def init_expert_share(key, cfg: ArchConfig, dtype=jnp.float32) -> dict:
+    """The router over all ``cfg.n_experts``, the ``cfg.n_held`` experts
+    held here (gate and up fused, gate first) and the shared MLP."""
+    e, d, f, sf = cfg.n_experts, cfg.d_model, cfg.d_ff, cfg.shared_width
+    kr, ku, kd, ksu, ksd = jax.random.split(key, 5)
+
+    def w(k, shape, fan_in):
+        return (jax.random.truncated_normal(k, -2, 2, shape, jnp.float32)
+                * fan_in ** -0.5).astype(dtype)
+
+    return {
+        # float32 and outside quantize_tree's paths: routing stays exact
+        "router": w(kr, (d, e), d).astype(jnp.float32),
+        "experts": {"gate_up": {"w": w(ku, (cfg.n_held, d, 2 * f), d)},
+                    "down": {"w": w(kd, (cfg.n_held, f, d), f)}},
+        "shared": {"gate_up": {"w": w(ksu, (d, 2 * sf), d)},
+                   "down": {"w": w(ksd, (sf, d), sf)}},
+    }
+
+
+def route_held(router, x: Array, cfg: ArchConfig) -> Array:
+    """Combine weights (T, held) of the held experts for rows x (T, D):
+    the router scores all ``n_experts``, the softmax runs over each row's
+    top-k scores (equal to softmax-then-renormalise over the top k), and
+    each held expert takes its row's weight where it is among the k, 0
+    elsewhere."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top_v, top_i = jax.lax.top_k(logits, cfg.top_k)
+    gates = jax.nn.softmax(top_v, axis=-1)                 # (T, k)
+    held = cfg.expert_offset + jnp.arange(cfg.n_held)
+    return jnp.einsum("tk,tke->te", gates,
+                      (top_i[..., None] == held).astype(jnp.float32))
+
+
+def held_experts(experts: dict, layer, x: Array, comb: Array,
+                 cfg: ArchConfig, mode: QuantMode) -> Array:
+    """sum_e comb[:, e] * FFN_e(x) over the held experts of layer
+    ``layer``: rows x (T, D), combine weights comb (T, held).  Every row
+    goes through every held expert (capacity = rows), so no routed token
+    is dropped at any length; ``comb`` zeroes the rows an expert was not
+    routed.  Int8 weights (stacked over layers, read whole) go through
+    ``ops.moe_w8a16``; float ones through an einsum."""
+    gu_w, dn_w = experts["gate_up"]["w"], experts["down"]["w"]
+    counts = jnp.sum(comb > 0, axis=0).astype(jnp.int32)   # (held,)
+    if mode.w8a8:
+        # the program's W8A8 path: the rows rounded to int8 on entry
+        x = _fake_quant(x)
+    if isinstance(gu_w, QTensor):
+        gu = kops.moe_w8a16(x, gu_w, layer, counts, interpret=mode.interpret)
+    else:
+        gu = jnp.einsum("td,edf->etf", x.astype(jnp.float32),
+                        gu_w[layer].astype(jnp.float32))
+    g, u = jnp.split(gu, 2, axis=-1)                       # (held, T, F)
+    act = jax.nn.silu(g) * u * comb.T[..., None]
+    act = act.astype(x.dtype)
+    if mode.w8a8:
+        act = _fake_quant(act)
+    if isinstance(dn_w, QTensor):
+        out = kops.moe_w8a16(act, dn_w, layer, counts,
+                             interpret=mode.interpret)
+    else:
+        out = jnp.einsum("etf,efd->etd", act.astype(jnp.float32),
+                         dn_w[layer].astype(jnp.float32))
+    return jnp.sum(out, axis=0)
+
+
+def _fake_quant(x: Array) -> Array:
+    """Round to int8 with one scale for the tensor, back in x's dtype."""
+    xf = x.astype(jnp.float32)
+    sc = jnp.maximum(jnp.max(jnp.abs(xf)), 1e-6) / 127.0
+    return (jnp.round(xf / sc) * sc).astype(x.dtype)
+
+
+def shared_mlp(p: dict, x: Array, mode: QuantMode) -> Array:
+    """The shared SiLU-gated MLP, gate and up fused (gate first)."""
+    g, u = jnp.split(linear(p["gate_up"], x, mode=mode), 2, axis=-1)
+    return linear(p["down"], (jax.nn.silu(g) * u).astype(x.dtype),
+                  mode=mode)
+
+
+def expert_share(p: dict, layer, x: Array, cfg: ArchConfig, *,
+                 mode: QuantMode = FP) -> Array:
+    """One MoE block as this chip computes it: the held experts' part of
+    the routed output plus the shared MLP, counted once.  ``p`` holds the
+    layer-stacked ``router``, ``experts`` and ``shared`` leaves; x
+    (..., D).  What the experts held elsewhere would add is left out."""
+    lead, d = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, d)
+    router = jax.lax.dynamic_index_in_dim(p["router"], layer, 0, False)
+    shared = jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False),
+        p["shared"])
+    with jax.named_scope("moe_router"):
+        comb = route_held(router, xt, cfg)
+    with jax.named_scope("moe_experts"):
+        routed = held_experts(p["experts"], layer, xt, comb, cfg, mode)
+    with jax.named_scope("moe_shared"):
+        out = routed.astype(jnp.float32) + shared_mlp(
+            shared, xt, mode).astype(jnp.float32)
+    return out.astype(x.dtype).reshape(*lead, d)
+
+
+# ---------------------------------------------------------------------------
 # full model: attention from transformer.py + MoE FFN
 # ---------------------------------------------------------------------------
 

@@ -19,20 +19,22 @@ import jax
 
 from repro.configs.base import ArchConfig
 from repro.core.qlinear import FP, QuantMode
-from repro.models import encdec, moe, rglru, ssm, transformer, vision
+from repro.models import (encdec, hybrid_moe, moe, rglru, ssm, transformer,
+                          vision)
 
 _MODULES = {
     "dense": transformer,
     "moe": moe,
     "ssm": ssm,
     "hybrid": rglru,
+    "hybrid_moe": hybrid_moe,
     "encdec": encdec,
     "vlm": vision,
 }
 
 
 # families whose decode state is recurrent: it freezes for inactive rows
-_RECURRENT = ("ssm", "hybrid")
+_RECURRENT = ("ssm", "hybrid", "hybrid_moe")
 
 
 def module_for(cfg: ArchConfig):
@@ -76,7 +78,7 @@ def init_cache(cfg: ArchConfig, batch: int, s_max: int, dtype=None):
 def apply_decode(params, cfg: ArchConfig, batch: dict, cache, *,
                  mode: QuantMode = FP, active=None):
     """One decode step.  ``active`` (B,) bool, default every row: the
-    slot engine's freeze.  The recurrent families (ssm/hybrid) take it
+    slot engine's freeze.  The recurrent families (_RECURRENT) take it
     into their decode and keep inactive rows' state bitwise; their state
     has no ``valid_len`` frontier to hide a clobbered row behind.  The
     other families' caches are positional (stale entries are masked on
@@ -216,3 +218,20 @@ def cache_batch_axes(cfg: ArchConfig, cache: dict) -> dict:
     if hasattr(m, "cache_batch_axes"):
         return m.cache_batch_axes(cache)
     return {k: 1 for k in cache}
+
+
+# the kind of each decode-cache leaf, by its name
+_CACHE_KINDS = {"h": "ssd_state", "rnn_h": "rnn_state", "lo_rnn_h": "rnn_state",
+                "conv": "conv", "lo_conv": "conv", "k": "kv", "v": "kv",
+                "k_scale": "kv", "v_scale": "kv"}
+
+
+def cache_bytes(cache: dict) -> dict:
+    """Bytes of a decode cache by kind: ``ssd_state``, ``rnn_state``,
+    ``conv`` and ``kv``; any other leaf under its own name."""
+    out: dict = {}
+    for name, leaf in cache.items():
+        kind = _CACHE_KINDS.get(name, name)
+        out[kind] = out.get(kind, 0) + sum(
+            x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(leaf))
+    return out

@@ -46,7 +46,7 @@ def init_ssd_layer(key, cfg: ArchConfig, dtype=jnp.float32) -> dict:
     d, din, n, nh = (cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_heads)
     k1, k2, k3, k4 = jax.random.split(key, 4)
     # fused in_proj: [x (din), z (din), B (n), C (n), dt (nh)]
-    return {
+    p = {
         "norm": L.init_rmsnorm(d, dtype),
         "in_proj": init_linear(k1, d, 2 * din + 2 * n + nh, bias=False,
                                dtype=dtype),
@@ -60,6 +60,9 @@ def init_ssd_layer(key, cfg: ArchConfig, dtype=jnp.float32) -> dict:
         "out_proj": init_linear(k3, din, d, bias=False, dtype=dtype,
                                 scale=din ** -0.5),
     }
+    if cfg.ssm_gated_norm:
+        p["gate_norm"] = L.init_rmsnorm(din, dtype)
+    return p
 
 
 def _causal_conv(x: Array, w: Array, b: Array,
@@ -145,7 +148,7 @@ def _mix_in(p: dict, x: Array, cfg: ArchConfig, mode: QuantMode,
     (xh (B,S,H,hd), z, Bm, Cm, dt (B,S,H) float32, new conv tail)."""
     b, s, _ = x.shape
     din, n, nh, hd = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_headdim
-    h_in = L.rmsnorm(p["norm"], x)
+    h_in = L.rmsnorm(p["norm"], x, cfg.norm_eps)
     proj = linear(p["in_proj"], h_in, mode=mode)
     xz, z, Bm, Cm, dt = jnp.split(
         proj, [din, 2 * din, 2 * din + n, 2 * din + 2 * n], axis=-1)
@@ -160,13 +163,19 @@ def _mix_in(p: dict, x: Array, cfg: ArchConfig, mode: QuantMode,
 
 
 def _mix_out(p: dict, x: Array, y: Array, xh: Array, z: Array,
-             mode: QuantMode) -> Array:
-    """Skip term, gate and out-projection; residual added."""
+             cfg: ArchConfig, mode: QuantMode) -> Array:
+    """Skip term, gate (with the gated RMSNorm where ``cfg.ssm_gated_norm``)
+    and out-projection; residual added, scaled by
+    ``cfg.residual_multiplier``."""
     b, s, nh, hd = xh.shape
     y = y + p["D"][None, None, :, None] * xh.astype(jnp.float32)
-    y = (y.reshape(b, s, nh * hd) * jax.nn.silu(z.astype(jnp.float32))
-         ).astype(x.dtype)
-    out = linear(p["out_proj"], y, mode=mode)
+    y = y.reshape(b, s, nh * hd) * jax.nn.silu(z.astype(jnp.float32))
+    if cfg.ssm_gated_norm:
+        y = L.rmsnorm(p["gate_norm"], y, cfg.norm_eps)
+    out = linear(p["out_proj"], y.astype(x.dtype), mode=mode)
+    if cfg.residual_multiplier != 1.0:
+        out = (out.astype(jnp.float32) * cfg.residual_multiplier
+               ).astype(out.dtype)
     return x + constrain(out, "act")
 
 
@@ -175,7 +184,7 @@ def ssd_layer(p: dict, x: Array, cfg: ArchConfig, *,
     """One Mamba-2 block over a full sequence (chunked SSD)."""
     xh, z, Bm, Cm, dt, _ = _mix_in(p, x, cfg, mode)
     y = _ssd_chunked(xh, dt, p["a_log"], Bm, Cm, cfg.ssm_chunk)
-    return _mix_out(p, x, y, xh, z, mode)
+    return _mix_out(p, x, y, xh, z, cfg, mode)
 
 
 def ssd_decode_layer(p: dict, x: Array, h: Array, conv: Array, layer: Array,
@@ -196,7 +205,7 @@ def ssd_decode_layer(p: dict, x: Array, h: Array, conv: Array, layer: Array,
     a_step = jnp.exp(dt[:, 0] * (-jnp.exp(p["a_log"]))[None])   # (B,H)
     h, y = ops.ssm_state_update(h, layer, a_step, xh[:, 0], dt[:, 0],
                                 Bm[:, 0], Cm[:, 0], fresh, active)
-    return _mix_out(p, x, y[:, None], xh, z, mode), h, conv
+    return _mix_out(p, x, y[:, None], xh, z, cfg, mode), h, conv
 
 
 # ---------------------------------------------------------------------------

@@ -178,3 +178,71 @@ def test_ssm_slot_step_updates_state_in_place(one_chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < layer_state
     assert mem.alias_size_in_bytes >= cache_bytes
+
+
+GRANITE = get_config("granite-4.0-h-small")
+
+
+@pytest.mark.parametrize("k,n,per_expert", [(4096, 1536, False),
+                                            (768, 4096, True)])
+def test_moe_w8a16_compiles(one_chip, k, n, per_expert):
+    """The held-expert kernel at granite-4.0-h-small's widths: 9 experts
+    of one layer of a 40-layer stack, 16 rows (the slots), the
+    up-projection (rows shared by every expert) and the down-projection
+    (rows per expert)."""
+    from repro.kernels import moe_w8a16 as MW
+    from repro.kernels import ops
+    e, m = GRANITE.n_held, 16
+    x = ((e, m, k) if per_expert else (m, k), jnp.bfloat16)
+    text = _compile(one_chip, lambda x, w, s, c: MW.moe_w8a16(
+        x, w, s, jnp.int32(3), c, bm=m, bn=ops._lane_block(n),
+        bk=ops._lane_block(k)),
+        x, ((GRANITE.n_layers, e, k, n), jnp.int8),
+        ((GRANITE.n_layers, e, 1, n), jnp.float32), ((e,), jnp.int32))
+    assert "moe_w8a16" in text
+
+
+def test_granite_slot_step_reads_int8_experts_in_place(one_chip, monkeypatch):
+    """granite-4.0-h-small's slot step at published widths and 16 slots,
+    W8A16, depth cut to one period of the layer pattern (9 Mamba layers
+    and one attention layer, each with its MoE block): the held-expert
+    and state kernels are in it, no bf16 or float32 array of a held
+    expert's weight shape exists in it (the kernel converts int8 tiles in
+    VMEM), and its temporaries stay under two layers' state (71 MB here,
+    259 MB at all 40 layers): no copy of the state or of a weight
+    stack."""
+    from repro.core.qlinear import W8A16
+    from repro.kernels import ops
+    from repro.models import registry as R
+    from repro.runtime import steps as ST
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(GRANITE, n_layers=10,
+                              layer_types=GRANITE.layer_types[:10])
+    slots = 16
+    put = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = put(jax.eval_shape(lambda k: R.init_quantized(k, cfg),
+                                jax.random.PRNGKey(0)))
+    cache = put(jax.eval_shape(lambda: R.init_cache(cfg, slots, 512)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    step = jax.jit(ST.make_slot_decode_step(cfg, mode=W8A16),
+                   donate_argnums=(2,))
+    compiled = step.lower(params, i32(slots, 1), cache, i32(slots),
+                          jax.ShapeDtypeStruct((slots,), bool,
+                                               sharding=one_chip)).compile()
+    text = compiled.as_text()
+    calls = lambda name: [ln for ln in text.splitlines()
+                          if 'custom_call_target="tpu_custom_call"' in ln
+                          and f"%{name}" in ln]
+    assert calls("moe_w8a16") and calls("ssm_state_update")
+    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_held
+    for dims in ((e, d, 2 * f), (d, 2 * f), (e, f, d), (f, d)):
+        shape = ",".join(map(str, dims))
+        for dt in ("bf16", "f32"):
+            assert f"{dt}[{shape}]" not in text, (dt, shape)
+    nbytes = lambda x: x.size * x.dtype.itemsize
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * nbytes(cache["h"]) // 9  # 134 MB
+    assert mem.alias_size_in_bytes >= sum(nbytes(x) for x in cache.values())
