@@ -41,6 +41,16 @@ W8A16 = QuantMode(enabled=True, act_bits=16)
 W8A8 = QuantMode(enabled=True, act_bits=8)
 
 
+def layer_view(stack, layer):
+    """Layer ``layer`` of a layer-stacked parameter tree, as the int8 path
+    reads it: every float leaf sliced to the layer, every QTensor left
+    whole, for :func:`linear` to read at ``layer`` in place."""
+    return jax.tree_util.tree_map(
+        lambda a: a if isinstance(a, QTensor)
+        else jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        stack, is_leaf=lambda a: isinstance(a, QTensor))
+
+
 def init_linear(key, d_in: int, d_out: int, *, bias: bool = True,
                 dtype=jnp.float32, scale: Optional[float] = None) -> dict:
     """Truncated-normal init, std = 1/sqrt(d_in) unless overridden."""
@@ -53,14 +63,19 @@ def init_linear(key, d_in: int, d_out: int, *, bias: bool = True,
 
 
 def linear(params: dict, x: jax.Array, *, activation: str = "none",
-           mode: QuantMode = FP, compute_dtype=jnp.bfloat16) -> jax.Array:
-    """y = act(x @ w + b), dispatching on the weight's quantization state."""
+           mode: QuantMode = FP, compute_dtype=jnp.bfloat16,
+           layer=None) -> jax.Array:
+    """y = act(x @ w + b), dispatching on the weight's quantization state.
+
+    ``layer`` () int32: ``w`` is a QTensor layer stack (L, K, N), read in
+    place at that layer by the int8 kernel (see :func:`layer_view`); a
+    float weight comes already sliced, and ``layer`` is not used."""
     w = params["w"]
     b = params.get("b")
     if isinstance(w, QTensor):
         fn = ops.qmatmul_dynamic if mode.w8a8 else ops.qmatmul
-        return fn(x, w, b, activation=activation, out_dtype=x.dtype,
-                  interpret=mode.interpret)
+        return fn(x, w, b, layer=layer, activation=activation,
+                  out_dtype=x.dtype, interpret=mode.interpret)
     # fp path: bf16 compute, fp32 accumulate (XLA default on MXU)
     y = jnp.dot(x.astype(compute_dtype), w.astype(compute_dtype),
                 preferred_element_type=jnp.float32)

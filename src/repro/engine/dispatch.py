@@ -304,7 +304,8 @@ class _Lane:
         self.tag = tag
         self.order = order                 # dense gid = order * S + sid
         be = eng.backend
-        self.cfg, self.params = cfg, be.place_params(params)
+        self.cfg = cfg
+        self.params = be.place_params(R.serving_params(cfg, params))
         self.spec_k = spec_k               # 0 on lanes that can't draft
         self.dcfg = dcfg
         self.dparams = None if dparams is None else be.place_params(dparams)

@@ -130,21 +130,28 @@ def _bm_align(mode: str, x_dtype: str, out_dtype: str) -> int:
 
 def is_legal(cfg: TileConfig, *, mode: str, x_dtype: str = "bf16",
              out_dtype: str = "f32", has_bias: bool = True,
-             budget: int = DEFAULT_VMEM_BUDGET) -> bool:
-    """Alignment + budget legality of a tile config (shape-independent).
+             budget: int = DEFAULT_VMEM_BUDGET,
+             k: Optional[int] = None) -> bool:
+    """Alignment + budget legality of a tile config.
 
     - bm must honour the sublane minimum of BOTH the streamed x dtype and
       the output dtype (int8 32, bf16 16, f32 8) — the (bm, bn) out tile
       is a real block too;
     - bn / bk must be lane-aligned (128); w8a8 K-tiles additionally pack
-      two int8 per register lane, so bk must be a multiple of 256;
+      two int8 per register lane, so bk must be a multiple of 256.  A bk
+      equal to the problem's whole depth ``k`` is legal whatever its size
+      (a block spanning a whole dimension), and where ``k`` is given bk
+      must divide it: the kernels take no ragged K block;
     - the double-buffered working set must fit the VMEM budget.
     """
     if cfg.bm <= 0 or cfg.bm % _bm_align(mode, x_dtype, out_dtype) != 0:
         return False
-    if cfg.bn % LANE != 0 or cfg.bk % LANE != 0:
+    bk_align = 256 if mode == "w8a8" else LANE
+    if cfg.bn % LANE != 0:
         return False
-    if mode == "w8a8" and cfg.bk % 256 != 0:
+    if k is not None and (k % cfg.bk != 0):
+        return False
+    if cfg.bk % bk_align != 0 and cfg.bk != k:
         return False
     return vmem_bytes(cfg, mode=mode, x_dtype=x_dtype, out_dtype=out_dtype,
                       has_bias=has_bias) <= budget
@@ -159,7 +166,8 @@ def enumerate_candidates(m: int, k: int, n: int, *, mode: str = "w8a16",
 
     A block strictly larger than the smallest block covering the whole
     dimension only adds padding (same grid extent of 1), so at most one
-    such candidate per dimension survives.
+    such candidate per dimension survives.  bk is drawn from K's
+    divisors, or is K itself where no aligned candidate divides it.
     """
 
     def axis_pool(cands: Sequence[int], size: int, align: int) -> List[int]:
@@ -175,7 +183,8 @@ def enumerate_candidates(m: int, k: int, n: int, *, mode: str = "w8a16",
     bms = axis_pool(BM_CANDIDATES, m, _bm_align(mode, x_dtype, out_dtype))
     bns = axis_pool(BN_CANDIDATES, n, LANE)
     bk_align = 256 if mode == "w8a8" else LANE
-    bks = axis_pool(BK_CANDIDATES, k, bk_align)
+    bks = [c for c in BK_CANDIDATES if c % bk_align == 0 and k % c == 0] \
+        or [k]
     out = []
     for bm in bms:
         for bn in bns:
@@ -183,7 +192,7 @@ def enumerate_candidates(m: int, k: int, n: int, *, mode: str = "w8a16",
                 cfg = TileConfig(bm, bn, bk)
                 if is_legal(cfg, mode=mode, x_dtype=x_dtype,
                             out_dtype=out_dtype, has_bias=has_bias,
-                            budget=budget):
+                            budget=budget, k=k):
                     out.append(cfg)
     return out
 
@@ -376,20 +385,20 @@ def measure_config(m: int, k: int, n: int, cfg: TileConfig, *,
     from repro.kernels import qmatmul as _k
 
     mp = _round_up(m, cfg.bm)
-    kp = _round_up(k, cfg.bk)
-    np_ = _round_up(n, cfg.bn)
     key = jax.random.PRNGKey(0)
-    ws = jnp.ones((np_,), jnp.float32)
-    w = jax.random.randint(key, (kp, np_), -127, 127, jnp.int8)
+    ws = jnp.ones((1, 1, n), jnp.float32)
+    w = jax.random.randint(key, (1, k, n), -127, 127, jnp.int8)
+    layer = jnp.zeros((), jnp.int32)
     if mode == "w8a8":
-        x = jax.random.randint(jax.random.fold_in(key, 1), (mp, kp),
+        x = jax.random.randint(jax.random.fold_in(key, 1), (mp, k),
                                -127, 127, jnp.int8)
         fn = lambda: _k.qmatmul_w8a8(x, w, jnp.ones((), jnp.float32), ws,
-                                     None, **cfg.as_kwargs())
+                                     layer, None, **cfg.as_kwargs())
     else:
-        x = jax.random.normal(jax.random.fold_in(key, 1), (mp, kp),
+        x = jax.random.normal(jax.random.fold_in(key, 1), (mp, k),
                               jnp.float32).astype(jnp.bfloat16)
-        fn = lambda: _k.qmatmul_w8a16(x, w, ws, None, **cfg.as_kwargs())
+        fn = lambda: _k.qmatmul_w8a16(x, w, ws, layer, None,
+                                      **cfg.as_kwargs())
     fn().block_until_ready()                 # compile + warm
     t0 = time.perf_counter()
     for _ in range(iters):

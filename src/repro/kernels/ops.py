@@ -6,7 +6,11 @@ Responsibilities:
   `kernels.autotune.best_config` picks tuned tiles per (M, K, N, mode) —
   small-M decode problems get GEMV-style bm ∈ {8, 16, 32} row tiles
   instead of padding to 128 rows,
-- pad M/N/K up to the chosen block multiples and slice the result back,
+- pad M up to the chosen row block and slice the result back; weights,
+  scales and bias are never padded (a ragged last column block, and a
+  ``bk`` that divides K),
+- hand a layer-stacked int8 weight to the kernel whole, with the index of
+  the layer to read,
 - dispatch: TPU backend -> compiled Pallas kernel; CPU -> the jnp oracle
   (numerically identical contract) unless ``interpret=True`` is forced, which
   runs the actual kernel body through the Pallas interpreter for validation,
@@ -68,14 +72,16 @@ def _resolve_blocks(m: int, k: int, n: int, *, mode: str, x_dtype: str,
 @functools.partial(jax.jit, static_argnames=(
     "activation", "out_dtype", "interpret", "bm", "bn", "bk"))
 def qmatmul(x, w: QTensor, bias: Optional[jax.Array] = None, *,
-            x_q: Optional[QTensor] = None, activation: str = "none",
-            out_dtype=jnp.bfloat16, interpret: bool = False,
-            bm: Optional[int] = None, bn: Optional[int] = None,
-            bk: Optional[int] = None) -> jax.Array:
+            layer=None, x_q: Optional[QTensor] = None,
+            activation: str = "none", out_dtype=jnp.bfloat16,
+            interpret: bool = False, bm: Optional[int] = None,
+            bn: Optional[int] = None, bk: Optional[int] = None) -> jax.Array:
     """act((x @ dequant(w)) + bias) with int8 weights.
 
-    ``x`` fp array of shape (..., K); ``w`` QTensor (K, N) with per-column
-    scales.  If ``x_q`` is given (pre-quantized activations, per-tensor
+    ``x`` fp array of shape (..., K).  ``w`` a QTensor: one (K, N) matrix
+    with per-column scales, or, with ``layer`` () int32, a layer stack
+    (L, K, N) with (L, 1, N) scales of which layer ``layer`` is read, in
+    place.  If ``x_q`` is given (pre-quantized activations, per-tensor
     scale), the full w8a8 integer path runs; otherwise weight-only w8a16.
     ``bm``/``bn``/``bk`` override the autotuned tile shape when given.
     """
@@ -86,59 +92,56 @@ def qmatmul(x, w: QTensor, bias: Optional[jax.Array] = None, *,
     n = w.shape[-1]
     x2 = x.reshape(-1, kdim)
     m = x2.shape[0]
-
-    w_scale = w.scale.reshape(-1)
-    use_pallas = on_tpu() or interpret
-    run_interp = interpret and not on_tpu()
-
-    if x_q is not None:
+    w8a8 = x_q is not None
+    if w8a8:
         xq2 = x_q.values.reshape(-1, kdim)
         xs = x_q.scale.reshape(())
-        if use_pallas:
-            bm, bn, bk = _resolve_blocks(
-                m, kdim, n, mode="w8a8", x_dtype="int8",
-                out_dtype=_dtype_name(out_dtype),
-                has_bias=bias is not None, bm=bm, bn=bn, bk=bk)
-            xp = _pad_to(_pad_to(xq2, bm, 0), bk, 1)
-            wp = _pad_to(_pad_to(w.values, bk, 0), bn, 1)
-            wsp = _pad_to(w_scale, bn, 0)
-            bp = _pad_to(bias, bn, 0) if bias is not None else None
-            out = _k.qmatmul_w8a8(
-                xp, wp, xs, wsp, bp, bm=bm, bn=bn, bk=bk,
-                activation=activation, out_dtype=out_dtype,
-                interpret=run_interp)
-            return out[:m, :n].reshape(*lead, n)
-        out = _ref.qmatmul_w8a8_ref(
-            xq2, w.values, xs, w_scale, bias,
-            activation=activation, out_dtype=out_dtype)
+
+    if not (on_tpu() or interpret):
+        values, scale = w.values, w.scale
+        if layer is not None:
+            values, scale = (jax.lax.dynamic_index_in_dim(a, layer, 0, False)
+                             for a in (values, scale))
+        if w8a8:
+            out = _ref.qmatmul_w8a8_ref(
+                xq2, values, xs, scale.reshape(-1), bias,
+                activation=activation, out_dtype=out_dtype)
+        else:
+            out = _ref.qmatmul_w8a16_ref(
+                x2, values, scale.reshape(-1), bias,
+                activation=activation, out_dtype=out_dtype)
         return out.reshape(*lead, n)
 
-    if use_pallas:
-        bm, bn, bk = _resolve_blocks(
-            m, kdim, n, mode="w8a16", x_dtype=_dtype_name(x.dtype),
-            out_dtype=_dtype_name(out_dtype),
-            has_bias=bias is not None, bm=bm, bn=bn, bk=bk)
-        xp = _pad_to(_pad_to(x2, bm, 0), bk, 1)
-        wp = _pad_to(_pad_to(w.values, bk, 0), bn, 1)
-        wsp = _pad_to(w_scale, bn, 0)
-        bp = _pad_to(bias, bn, 0) if bias is not None else None
+    # one matrix is a stack of one: reshapes, no copies
+    stack = w.values.reshape(-1, kdim, n)
+    scales = w.scale.reshape(stack.shape[0], 1, n)
+    layer = jnp.zeros((), jnp.int32) if layer is None else layer
+    bm, bn, bk = _resolve_blocks(
+        m, kdim, n, mode="w8a8" if w8a8 else "w8a16",
+        x_dtype="int8" if w8a8 else _dtype_name(x.dtype),
+        out_dtype=_dtype_name(out_dtype),
+        has_bias=bias is not None, bm=bm, bn=bn, bk=bk)
+    run_interp = interpret and not on_tpu()
+    if w8a8:
+        out = _k.qmatmul_w8a8(
+            _pad_to(xq2, bm, 0), stack, xs, scales, layer, bias,
+            bm=bm, bn=bn, bk=bk, activation=activation,
+            out_dtype=out_dtype, interpret=run_interp)
+    else:
         out = _k.qmatmul_w8a16(
-            xp, wp, wsp, bp, bm=bm, bn=bn, bk=bk,
-            activation=activation, out_dtype=out_dtype,
-            interpret=run_interp)
-        return out[:m, :n].reshape(*lead, n)
-    out = _ref.qmatmul_w8a16_ref(
-        x2, w.values, w_scale, bias,
-        activation=activation, out_dtype=out_dtype)
-    return out.reshape(*lead, n)
+            _pad_to(x2, bm, 0), stack, scales, layer, bias,
+            bm=bm, bn=bn, bk=bk, activation=activation,
+            out_dtype=out_dtype, interpret=run_interp)
+    return out[:m].reshape(*lead, n)
 
 
-def qmatmul_dynamic(x, w: QTensor, bias=None, *, activation: str = "none",
-                    out_dtype=jnp.bfloat16, interpret: bool = False):
+def qmatmul_dynamic(x, w: QTensor, bias=None, *, layer=None,
+                    activation: str = "none", out_dtype=jnp.bfloat16,
+                    interpret: bool = False):
     """w8a8 with on-the-fly per-tensor activation quantization (the TPU's
     quantize-on-entry-to-UB behaviour)."""
     x_q = quantize(x.astype(jnp.float32), bits=8, axis=None)
-    return qmatmul(x, w, bias, x_q=x_q, activation=activation,
+    return qmatmul(x, w, bias, layer=layer, x_q=x_q, activation=activation,
                    out_dtype=out_dtype, interpret=interpret)
 
 

@@ -17,9 +17,18 @@ Two kernels:
 
 Dataflow / BlockSpec design (HW adaptation notes):
 
-- Grid is (M/bm, N/bn, K/bk) with K innermost ("arbitrary"); an int32/f32
-  accumulator tile lives in VMEM scratch across the K sweep — this is the
-  Accumulator bank.  Pallas's automatic pipelining double-buffers the incoming
+- The weights are a layer stack, (L, K, N) int8 with (L, 1, N) column
+  scales, and the layer to read is a scalar prefetched into the index
+  maps: a layer scan hands the kernel the whole stack, so no layer's
+  weights are sliced, relaid out or padded before the kernel reads them.
+  One matrix is a stack of one (``ops.qmatmul`` reshapes it, a bitcast).
+- Grid is (M/bm, cdiv(N, bn), K/bk) with K innermost ("arbitrary"); an
+  int32/f32 accumulator tile lives in VMEM scratch across the K sweep —
+  this is the Accumulator bank.  The last column block may be ragged: its
+  out-of-bounds columns read whatever lies past N and are never written
+  back.  ``bk`` divides K, since a ragged K block would add out-of-bounds
+  products into real outputs; only the rows (M) are padded, by
+  ``ops.qmatmul``.  Pallas's automatic pipelining double-buffers the incoming
   weight tiles, playing the role of the 4-tile-deep Weight FIFO.
 - Activations stream from the Unified Buffer analogue (VMEM blocks of x);
   weights stream from HBM (Weight Memory).  Ops/weight-byte of one call is
@@ -67,12 +76,56 @@ def _activate(x: jax.Array, activation: str) -> jax.Array:
     raise ValueError(f"unknown activation {activation!r}")
 
 
+def _linear_call(kernel, x, w, w_scale, layer, bias, extra, *, bm, bn, bk,
+                 acc_dtype, out_dtype, interpret, name):
+    """One pallas_call of an int8 linear kernel on layer ``layer`` of the
+    stack ``w`` (L, K, N) with column scales ``w_scale`` (L, 1, N).
+    ``extra`` are (operand, block shape) pairs read whole by every grid
+    step, between the weight scales and the bias."""
+    m, k = x.shape
+    nl, k2, n = w.shape
+    assert k == k2 and w_scale.shape == (nl, 1, n), (
+        x.shape, w.shape, w_scale.shape)
+    assert m % bm == 0 and k % bk == 0, \
+        f"rows {m} or depth {k} not a multiple of blocks {(bm, bk)}"
+    has_bias = bias is not None
+    in_specs = [
+        pl.BlockSpec((bm, bk), lambda i, j, kk, lr: (i, kk)),     # acts (UB)
+        pl.BlockSpec((None, bk, bn),                              # weights
+                     lambda i, j, kk, lr: (lr[0], kk, j)),        # (FIFO)
+        pl.BlockSpec((None, 1, bn),                               # col scales
+                     lambda i, j, kk, lr: (lr[0], 0, j)),
+    ]
+    operands = [x, w, w_scale.astype(jnp.float32)]
+    for operand, block in extra:
+        in_specs.append(pl.BlockSpec(block, lambda i, j, kk, lr: (0, 0)))
+        operands.append(operand)
+    if has_bias:
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk, lr: (0, j)))
+        operands.append(bias.reshape(1, n).astype(jnp.float32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(m // bm, pl.cdiv(n, bn), k // bk),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, lr: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)])       # Accumulators
+    return pl.pallas_call(
+        functools.partial(kernel, nk=k // bk, has_bias=has_bias),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), *operands)
+
+
 # ---------------------------------------------------------------------------
 # w8a8: int8 x int8 -> int32 accumulate -> dequant -> act
 # ---------------------------------------------------------------------------
 
-def _w8a8_kernel(x_ref, w_ref, xs_ref, ws_ref, *rest,
+def _w8a8_kernel(layer_ref, x_ref, w_ref, ws_ref, xs_ref, *rest,
                  nk: int, activation: str, out_dtype, has_bias: bool):
+    del layer_ref                               # consumed by the index maps
     if has_bias:
         b_ref, o_ref, acc_ref = rest
     else:
@@ -105,60 +158,33 @@ def _w8a8_kernel(x_ref, w_ref, xs_ref, ws_ref, *rest,
     jax.jit,
     static_argnames=("bm", "bn", "bk", "activation", "out_dtype", "interpret"))
 def qmatmul_w8a8(x: jax.Array, w: jax.Array, x_scale: jax.Array,
-                 w_scale: jax.Array, bias: Optional[jax.Array] = None, *,
+                 w_scale: jax.Array, layer: jax.Array,
+                 bias: Optional[jax.Array] = None, *,
                  bm: int = 128, bn: int = 128, bk: int = 256,
                  activation: str = "none", out_dtype=jnp.float32,
                  interpret: bool = False) -> jax.Array:
-    """out = act((x_int8 @ w_int8) * x_scale * w_scale + bias).
+    """out = act((x_int8 @ w_int8[layer]) * x_scale * w_scale[layer] + bias).
 
-    x: (M, K) int8.  w: (K, N) int8.  x_scale: scalar ().  w_scale: (N,).
-    bias: (N,) fp or None.  M, N, K padded to block multiples by ops.py.
+    x: (M, K) int8, M a multiple of bm.  w: (L, K, N) int8.  x_scale:
+    scalar ().  w_scale: (L, 1, N).  layer: () or (1,) int32.  bias: (N,)
+    fp or None.  bk divides K; N needs no multiple of bn.
     """
-    m, k = x.shape
-    k2, n = w.shape
-    assert k == k2, (x.shape, w.shape)
-    assert m % bm == 0 and n % bn == 0 and k % bk == 0, \
-        f"unpadded shapes {(m, n, k)} vs blocks {(bm, bn, bk)}"
-    nk = k // bk
-
+    kernel = functools.partial(_w8a8_kernel, activation=activation,
+                               out_dtype=out_dtype)
     xs = x_scale.reshape(1, 1).astype(jnp.float32)
-    ws = w_scale.reshape(1, n).astype(jnp.float32)
-    has_bias = bias is not None
-
-    kernel = functools.partial(
-        _w8a8_kernel, nk=nk, activation=activation, out_dtype=out_dtype,
-        has_bias=has_bias)
-
-    in_specs = [
-        pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),   # acts (UB)
-        pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),   # weights (FIFO)
-        pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),      # act scale
-        pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),     # col scales
-    ]
-    operands = (x, w, xs, ws)
-    if has_bias:
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)))
-        operands += (bias.reshape(1, n).astype(jnp.float32),)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(m // bm, n // bn, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],       # Accumulators
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(*operands)
+    return _linear_call(kernel, x, w, w_scale, layer, bias, [(xs, (1, 1))],
+                        bm=bm, bn=bn, bk=bk, acc_dtype=jnp.int32,
+                        out_dtype=out_dtype, interpret=interpret,
+                        name="qmatmul_w8a8")
 
 
 # ---------------------------------------------------------------------------
 # w8a16: fp acts x int8 weights (dequant in-kernel), fp32 accumulate
 # ---------------------------------------------------------------------------
 
-def _w8a16_kernel(x_ref, w_ref, ws_ref, *rest,
+def _w8a16_kernel(layer_ref, x_ref, w_ref, ws_ref, *rest,
                   nk: int, activation: str, out_dtype, has_bias: bool):
+    del layer_ref                               # consumed by the index maps
     if has_bias:
         b_ref, o_ref, acc_ref = rest
     else:
@@ -189,46 +215,20 @@ def _w8a16_kernel(x_ref, w_ref, ws_ref, *rest,
     jax.jit,
     static_argnames=("bm", "bn", "bk", "activation", "out_dtype", "interpret"))
 def qmatmul_w8a16(x: jax.Array, w: jax.Array, w_scale: jax.Array,
-                  bias: Optional[jax.Array] = None, *,
+                  layer: jax.Array, bias: Optional[jax.Array] = None, *,
                   bm: int = 128, bn: int = 128, bk: int = 256,
                   activation: str = "none", out_dtype=jnp.bfloat16,
                   interpret: bool = False) -> jax.Array:
-    """out = act((x_fp @ dequant(w_int8)) + bias); weight-only quantization.
+    """out = act((x_fp @ dequant(w_int8[layer])) + bias); weight-only
+    quantization.
 
-    x: (M, K) bf16/f32.  w: (K, N) int8.  w_scale: (N,).
+    x: (M, K) bf16/f32, M a multiple of bm.  w: (L, K, N) int8.  w_scale:
+    (L, 1, N).  layer: () or (1,) int32.  bias: (N,) fp or None.  bk
+    divides K; N needs no multiple of bn.
     """
-    m, k = x.shape
-    k2, n = w.shape
-    assert k == k2, (x.shape, w.shape)
-    assert m % bm == 0 and n % bn == 0 and k % bk == 0, \
-        f"unpadded shapes {(m, n, k)} vs blocks {(bm, bn, bk)}"
-    nk = k // bk
-
-    ws = w_scale.reshape(1, n).astype(jnp.float32)
-    has_bias = bias is not None
-
-    kernel = functools.partial(
-        _w8a16_kernel, nk=nk, activation=activation, out_dtype=out_dtype,
-        has_bias=has_bias)
-
-    in_specs = [
-        pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-        pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-    ]
-    operands = (x, w, ws)
-    if has_bias:
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)))
-        operands += (bias.reshape(1, n).astype(jnp.float32),)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(m // bm, n // bn, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(*operands)
+    kernel = functools.partial(_w8a16_kernel, activation=activation,
+                               out_dtype=out_dtype)
+    return _linear_call(kernel, x, w, w_scale, layer, bias, [],
+                        bm=bm, bn=bn, bk=bk, acc_dtype=jnp.float32,
+                        out_dtype=out_dtype, interpret=interpret,
+                        name="qmatmul_w8a16")

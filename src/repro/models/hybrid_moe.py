@@ -19,7 +19,9 @@ the attention layers, ``moe`` over every layer.  The layers run as a scan
 over periods of the layer pattern; inside a period each run of one mixer
 kind is a scan of its own, so the program holds one body per run of the
 period (three for granite's 5 Mamba, 1 attention, 4 Mamba), not one per
-layer.  Each body indexes the stacks at its layer's place.
+layer.  Each body indexes the stacks at its layer's place; in the decode
+step the int8 linears are not sliced but read in place at that index
+(``qlinear.layer_view``).
 
 Decode state: the stacked float32 SSD state ``h`` (L_mamba, B, H, hd, N)
 and conv tails, updated in place by ``ops.ssm_state_update`` as in
@@ -35,7 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, layer_period
-from repro.core.qlinear import FP, QuantMode
+from repro.core.qlinear import FP, QuantMode, layer_view
 from repro.models import layers as L
 from repro.models import moe, ssm
 
@@ -146,6 +148,11 @@ def init(key, cfg: ArchConfig, dtype=jnp.float32) -> dict:
     }
 
 
+def serving_params(params: dict) -> dict:
+    """The parameters as the engine serves them (``ssm.pad_in_proj``)."""
+    return {**params, "mamba": ssm.pad_in_proj(params["mamba"])}
+
+
 def init_cache(cfg: ArchConfig, batch: int, s_max: int,
                dtype=jnp.bfloat16) -> dict:
     """SSD state (float32) and conv tails of the Mamba layers, K/V of the
@@ -215,20 +222,20 @@ def decode_step(params: dict, tokens: Array, cache: dict, cache_index: Array,
         x, h, conv, k, v = c
         with jax.named_scope("mamba_mixer"):
             x, h, conv = ssm.ssd_decode_layer(
-                _at(params["mamba"], m), x, h, conv, m, fresh, active, cfg,
-                mode=mode)
+                layer_view(params["mamba"], m), x, h, conv, m, fresh,
+                active, cfg, mode=mode)
         return (_moe_residual(params, l, x, cfg, mode), h, conv, k, v)
 
     def attention(c, a, l):
         x, h, conv, k, v = c
-        ap = _at(params["attn"], a)
+        ap = layer_view(params["attn"], a)
         with jax.named_scope("attention_mixer"):
             kl = jax.lax.dynamic_index_in_dim(k, a, 0, keepdims=False)
             vl = jax.lax.dynamic_index_in_dim(v, a, 0, keepdims=False)
             out, (kn, vn) = L.attention(
                 ap["attn"], L.rmsnorm(ap["norm"], x, cfg.norm_eps), acfg,
                 mode=mode, positions=idx[:, None], kv_cache=(kl, vl),
-                cache_index=idx, append_only=True)
+                cache_index=idx, append_only=True, layer=a)
             x = x + _scaled(out, cfg)
 
             def write(c, new):                 # the new token, active rows

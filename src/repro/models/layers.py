@@ -231,6 +231,7 @@ def attention(p: dict, x: Array, cfg: AttnConfig, *,
               xattn_valid_len: Optional[Array] = None,
               append_only: bool = False,
               block_tables: Optional[Array] = None,
+              layer: Optional[Array] = None,
               ) -> Tuple[Array, Optional[Tuple[Array, Array]]]:
     """GQA attention with three modes:
 
@@ -263,10 +264,13 @@ def attention(p: dict, x: Array, cfg: AttnConfig, *,
     contiguous non-append path; ``new_cache`` returns just the new-token
     entries for the caller to scatter through the table outside the layer
     scan (see :func:`paged_append`).
+
+    ``layer`` () int32: the int8 projections are layer stacks read in
+    place at that layer (``qlinear.layer_view``).
     """
     b, s, d = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = linear(p["wq"], x, mode=mode).reshape(b, s, h, hd)
+    q = linear(p["wq"], x, mode=mode, layer=layer).reshape(b, s, h, hd)
     if xattn_precomputed is not None:
         # §Perf iteration D: source K/V were projected ONCE at prime time
         # (encoder frames / vision patches are static across decode steps)
@@ -274,9 +278,9 @@ def attention(p: dict, x: Array, cfg: AttnConfig, *,
         xattn_kv = k    # flags the non-causal source-attention path below
     else:
         kv_src = xattn_kv if xattn_kv is not None else x
-        k = linear(p["wk"], kv_src, mode=mode).reshape(
+        k = linear(p["wk"], kv_src, mode=mode, layer=layer).reshape(
             b, kv_src.shape[1], kvh, hd)
-        v = linear(p["wv"], kv_src, mode=mode).reshape(
+        v = linear(p["wv"], kv_src, mode=mode, layer=layer).reshape(
             b, kv_src.shape[1], kvh, hd)
 
     if positions is None:
@@ -400,7 +404,8 @@ def attention(p: dict, x: Array, cfg: AttnConfig, *,
                     out_dtype=jnp.float32)
             out = out.astype(x.dtype).reshape(b, s, h, hd)
             out = constrain(out, "act_heads")
-            out = linear(p["wo"], out.reshape(b, s, h * hd), mode=mode)
+            out = linear(p["wo"], out.reshape(b, s, h * hd), mode=mode,
+                         layer=layer)
             return constrain(out, "act"), new_cache
         q5 = q.reshape(b, s, kvh, g, hd)
         scale = hd ** -0.5 if cfg.sm_scale is None else cfg.sm_scale
@@ -467,7 +472,7 @@ def attention(p: dict, x: Array, cfg: AttnConfig, *,
                                      kv_valid_len=xattn_valid_len,
                                      sm_scale=cfg.sm_scale)
     out = constrain(out, "act_heads")
-    out = linear(p["wo"], out.reshape(b, s, h * hd), mode=mode)
+    out = linear(p["wo"], out.reshape(b, s, h * hd), mode=mode, layer=layer)
     return constrain(out, "act"), new_cache
 
 

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.core.qlinear import FP, QuantMode, init_linear, linear
+from repro.core.qlinear import FP, QuantMode, init_linear, layer_view, linear
 from repro.core.quant import QTensor
 from repro.kernels import ops as kops
 from repro.models import layers as L
@@ -235,11 +235,13 @@ def _fake_quant(x: Array) -> Array:
     return (jnp.round(xf / sc) * sc).astype(x.dtype)
 
 
-def shared_mlp(p: dict, x: Array, mode: QuantMode) -> Array:
-    """The shared SiLU-gated MLP, gate and up fused (gate first)."""
-    g, u = jnp.split(linear(p["gate_up"], x, mode=mode), 2, axis=-1)
+def shared_mlp(p: dict, x: Array, mode: QuantMode, layer=None) -> Array:
+    """The shared SiLU-gated MLP, gate and up fused (gate first); with
+    ``layer``, int8 weights are stacks read at that layer."""
+    g, u = jnp.split(linear(p["gate_up"], x, mode=mode, layer=layer), 2,
+                     axis=-1)
     return linear(p["down"], (jax.nn.silu(g) * u).astype(x.dtype),
-                  mode=mode)
+                  mode=mode, layer=layer)
 
 
 def expert_share(p: dict, layer, x: Array, cfg: ArchConfig, *,
@@ -251,16 +253,14 @@ def expert_share(p: dict, layer, x: Array, cfg: ArchConfig, *,
     lead, d = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, d)
     router = jax.lax.dynamic_index_in_dim(p["router"], layer, 0, False)
-    shared = jax.tree_util.tree_map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False),
-        p["shared"])
+    shared = layer_view(p["shared"], layer)
     with jax.named_scope("moe_router"):
         comb = route_held(router, xt, cfg)
     with jax.named_scope("moe_experts"):
         routed = held_experts(p["experts"], layer, xt, comb, cfg, mode)
     with jax.named_scope("moe_shared"):
         out = routed.astype(jnp.float32) + shared_mlp(
-            shared, xt, mode).astype(jnp.float32)
+            shared, xt, mode, layer).astype(jnp.float32)
     return out.astype(x.dtype).reshape(*lead, d)
 
 

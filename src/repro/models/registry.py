@@ -47,13 +47,24 @@ def init(key, cfg: ArchConfig, dtype=None):
 
 
 def init_quantized(key, cfg: ArchConfig):
-    """``quantize_tree(init(key, cfg), min_size=2048)`` under ONE jit whose
-    only output is the int8 tree, so the float32 tree never exists whole
-    on the device.  At starcoder2-3b's widths that tree is 12.1 GB of a 16 GB
-    chip; compiled for a v5e, this program's temporaries peak at the
-    largest float32 leaf (4.5 GB) beside the 3.0 GB int8 output."""
+    """``serving_params(quantize_tree(init(key, cfg), min_size=2048))``
+    under ONE jit whose only output is the int8 tree, so the float32 tree
+    never exists whole on the device.  At starcoder2-3b's widths that tree
+    is 12.1 GB of a 16 GB chip; compiled for a v5e, this program's
+    temporaries peak at the largest float32 leaf (4.5 GB) beside the
+    3.0 GB int8 output."""
     from repro.core.quant import quantize_tree
-    return jax.jit(lambda k: quantize_tree(init(k, cfg), min_size=2048))(key)
+    return jax.jit(lambda k: serving_params(
+        cfg, quantize_tree(init(k, cfg), min_size=2048)))(key)
+
+
+def serving_params(cfg: ArchConfig, params):
+    """``params`` laid out as the engine serves them: the family's
+    ``serving_params`` hook where it has one (ssm and hybrid_moe pad the
+    int8 SSD in-projection to a multiple of 128 columns), else ``params``
+    itself.  Applying it twice changes nothing."""
+    hook = getattr(module_for(cfg), "serving_params", None)
+    return params if hook is None else hook(params)
 
 
 def apply_forward(params, cfg: ArchConfig, batch: dict, *,

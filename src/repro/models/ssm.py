@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.core.qlinear import FP, QuantMode, init_linear, linear
+from repro.core.qlinear import FP, QuantMode, init_linear, layer_view, linear
+from repro.core.quant import QTensor
 from repro.kernels import ops
 from repro.models import layers as L
 from repro.runtime.sharding import constrain
@@ -143,15 +144,19 @@ def _ssd_chunked(xh: Array, dt: Array, a_log: Array, Bm: Array, Cm: Array,
 
 
 def _mix_in(p: dict, x: Array, cfg: ArchConfig, mode: QuantMode,
-            conv_state: Array = None):
-    """Norm, in-projection and causal conv of one block.  Returns
-    (xh (B,S,H,hd), z, Bm, Cm, dt (B,S,H) float32, new conv tail)."""
+            conv_state: Array = None, layer: Array = None):
+    """Norm, in-projection and causal conv of one block (``layer``: the
+    int8 linears are stacks read at that layer, see ``linear``).  Returns
+    (xh (B,S,H,hd), z, Bm, Cm, dt (B,S,H) float32, new conv tail).  The
+    in-projection's pieces are taken by their widths: columns past them
+    (``pad_in_proj``'s zeros) are dropped."""
     b, s, _ = x.shape
     din, n, nh, hd = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_headdim
     h_in = L.rmsnorm(p["norm"], x, cfg.norm_eps)
-    proj = linear(p["in_proj"], h_in, mode=mode)
-    xz, z, Bm, Cm, dt = jnp.split(
-        proj, [din, 2 * din, 2 * din + n, 2 * din + 2 * n], axis=-1)
+    proj = linear(p["in_proj"], h_in, mode=mode, layer=layer)
+    xz, z, Bm, Cm, dt, _ = jnp.split(
+        proj, [din, 2 * din, 2 * din + n, 2 * din + 2 * n,
+               2 * din + 2 * n + nh], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + p["dt_bias"][None, None])       # (B,S,H)
 
@@ -163,7 +168,7 @@ def _mix_in(p: dict, x: Array, cfg: ArchConfig, mode: QuantMode,
 
 
 def _mix_out(p: dict, x: Array, y: Array, xh: Array, z: Array,
-             cfg: ArchConfig, mode: QuantMode) -> Array:
+             cfg: ArchConfig, mode: QuantMode, layer: Array = None) -> Array:
     """Skip term, gate (with the gated RMSNorm where ``cfg.ssm_gated_norm``)
     and out-projection; residual added, scaled by
     ``cfg.residual_multiplier``."""
@@ -172,7 +177,7 @@ def _mix_out(p: dict, x: Array, y: Array, xh: Array, z: Array,
     y = y.reshape(b, s, nh * hd) * jax.nn.silu(z.astype(jnp.float32))
     if cfg.ssm_gated_norm:
         y = L.rmsnorm(p["gate_norm"], y, cfg.norm_eps)
-    out = linear(p["out_proj"], y.astype(x.dtype), mode=mode)
+    out = linear(p["out_proj"], y.astype(x.dtype), mode=mode, layer=layer)
     if cfg.residual_multiplier != 1.0:
         out = (out.astype(jnp.float32) * cfg.residual_multiplier
                ).astype(out.dtype)
@@ -191,7 +196,9 @@ def ssd_decode_layer(p: dict, x: Array, h: Array, conv: Array, layer: Array,
                      fresh: Array, active: Array, cfg: ArchConfig, *,
                      mode: QuantMode = FP) -> Tuple[Array, Array, Array]:
     """One Mamba-2 block, one token, on layer ``layer`` of the stacked
-    state: h (L, B, H, hd, N) float32 and conv (L, B, width-1, C).
+    state: h (L, B, H, hd, N) float32 and conv (L, B, width-1, C).  ``p``
+    is the block's stack at ``layer`` as ``qlinear.layer_view`` gives it:
+    float leaves sliced, int8 linears whole, read in place at ``layer``.
 
     O(1) decode: h' = a h + dt B x, y = C.h'.  Rows with ``fresh`` start
     from zero state and rows without ``active`` keep theirs bitwise; both
@@ -199,13 +206,35 @@ def ssd_decode_layer(p: dict, x: Array, h: Array, conv: Array, layer: Array,
     layer's state in place in one pass.  Returns (x, h, conv)."""
     conv_l = jax.lax.dynamic_index_in_dim(conv, layer, 0, keepdims=False)
     conv_in = jnp.where(fresh[:, None, None], jnp.zeros_like(conv_l), conv_l)
-    xh, z, Bm, Cm, dt, new_conv = _mix_in(p, x, cfg, mode, conv_in)
+    xh, z, Bm, Cm, dt, new_conv = _mix_in(p, x, cfg, mode, conv_in, layer)
     new_conv = jnp.where(active[:, None, None], new_conv, conv_l)
     conv = jax.lax.dynamic_update_index_in_dim(conv, new_conv, layer, 0)
     a_step = jnp.exp(dt[:, 0] * (-jnp.exp(p["a_log"]))[None])   # (B,H)
     h, y = ops.ssm_state_update(h, layer, a_step, xh[:, 0], dt[:, 0],
                                 Bm[:, 0], Cm[:, 0], fresh, active)
-    return _mix_out(p, x, y[:, None], xh, z, cfg, mode), h, conv
+    return _mix_out(p, x, y[:, None], xh, z, cfg, mode, layer), h, conv
+
+
+def pad_in_proj(block: dict) -> dict:
+    """``block`` (SSD blocks stacked over layers) with its int8
+    in-projection's columns padded with zeros to a multiple of 128, as
+    served.  XLA stores an int8 stack whose last dimension is not a
+    multiple of 128 with K minor, and the int8 kernel reads it row-major,
+    so every step would relayout the whole stack.  ``_mix_in`` drops the
+    extra columns.  Float weights and widths already aligned are returned
+    as they are, so a second call changes nothing."""
+    w = block["in_proj"]["w"]
+    pad = -w.shape[-1] % 128
+    if not isinstance(w, QTensor) or pad == 0:
+        return block
+    cols = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+    w = QTensor(values=cols(w.values), scale=cols(w.scale), bits=w.bits)
+    return {**block, "in_proj": {**block["in_proj"], "w": w}}
+
+
+def serving_params(params: dict) -> dict:
+    """The parameters as the engine serves them (``pad_in_proj``)."""
+    return {**params, "layers": pad_in_proj(params["layers"])}
 
 
 # ---------------------------------------------------------------------------
@@ -271,14 +300,13 @@ def decode_step(params: dict, tokens: Array, cache: dict, cache_index: Array,
     active = (jnp.ones((b,), bool) if active is None
               else jnp.asarray(active, bool))
 
-    def body(carry, inp):
+    def body(carry, layer):
         x, h, conv = carry
-        lp, layer = inp
+        lp = layer_view(params["layers"], layer)
         return ssd_decode_layer(lp, x, h, conv, layer, fresh, active, cfg,
                                 mode=mode), None
 
     (x, h, conv), _ = jax.lax.scan(
-        body, (x, cache["h"], cache["conv"]),
-        (params["layers"], jnp.arange(cfg.n_layers)))
+        body, (x, cache["h"], cache["conv"]), jnp.arange(cfg.n_layers))
     x = L.rmsnorm(params["ln_f"], x)
     return L.unembed(params["embed"], x), {"h": h, "conv": conv}

@@ -31,20 +31,20 @@ PROBLEMS = [
 @pytest.mark.parametrize("m,k,n", PROBLEMS)
 def test_candidates_are_legal(m, k, n, mode):
     """Every enumerated candidate is lane/sublane aligned and fits the
-    double-buffered working set in the VMEM budget."""
+    double-buffered working set in the VMEM budget; bk divides K (the
+    kernels take a ragged block on N only), and is lane-aligned unless it
+    spans the whole of K."""
     xd = AT.x_dtype_for(mode)
     cands = AT.enumerate_candidates(m, k, n, mode=mode)
     assert cands, f"no candidates for {(m, k, n)} {mode}"
     for c in cands:
         assert c.bm % AT.SUBLANE[xd] == 0, (c, xd)
-        assert c.bn % AT.LANE == 0 and c.bk % AT.LANE == 0, c
-        if mode == "w8a8":
-            assert c.bk % 256 == 0, c
+        assert c.bn % AT.LANE == 0, c
+        assert k % c.bk == 0, c
+        if c.bk != k:
+            assert c.bk % (256 if mode == "w8a8" else AT.LANE) == 0, c
         assert AT.vmem_bytes(c, mode=mode) <= AT.DEFAULT_VMEM_BUDGET, c
-        # padded problem divides exactly into blocks (the kernels assert
-        # divisibility; ops.py pads to these multiples)
-        for size, blk in ((m, c.bm), (n, c.bn), (k, c.bk)):
-            assert (-(-size // blk) * blk) % blk == 0
+        assert AT.is_legal(c, mode=mode, k=k), c
 
 
 @pytest.mark.parametrize("mode,x_dtype,m,want_bm", [
@@ -108,7 +108,7 @@ def test_registry_archs_within_vmem_budget(tmp_path):
         for row in AT.tune_arch(cfg, m_values=(8, 32), cache=cache):
             assert row["vmem_bytes"] <= AT.DEFAULT_VMEM_BUDGET, row
             tc = AT.TileConfig(row["bm"], row["bn"], row["bk"])
-            assert AT.is_legal(tc, mode=row["mode"]), row
+            assert AT.is_legal(tc, mode=row["mode"], k=row["k"]), row
 
 
 # ---------------------------------------------------------------------------
@@ -276,9 +276,10 @@ def test_bias_free_path_streams_no_bias_tile():
     keys = jax.random.split(jax.random.PRNGKey(3), 2)
     x = _rand(keys[0], (64, 256))
     w = quantize_weight(_rand(keys[1], (256, 128)))
-    got = K.qmatmul_w8a16(x.astype(jnp.float32), w.values,
-                          w.scale.reshape(-1), None, bm=64, bn=128, bk=256,
-                          interpret=True, out_dtype=jnp.float32)
+    got = K.qmatmul_w8a16(x.astype(jnp.float32), w.values[None],
+                          w.scale.reshape(1, 1, -1), jnp.int32(0), None,
+                          bm=64, bn=128, bk=256, interpret=True,
+                          out_dtype=jnp.float32)
     want = ref.qmatmul_w8a16_ref(x, w.values, w.scale.reshape(-1), None,
                                  out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -104,7 +104,7 @@ def test_rows_are_sane(bench_doc):
             # the autotuner never ships a config exceeding the VMEM budget
             assert row["vmem_bytes"] <= AT.DEFAULT_VMEM_BUDGET
             tc = AT.TileConfig(row["bm"], row["bn"], row["bk"])
-            assert AT.is_legal(tc, mode=row["mode"]), row
+            assert AT.is_legal(tc, mode=row["mode"], k=row["k"]), row
         elif row["kind"] == "engine":
             assert row["p99_s"] > 0 and row["tokens_per_s"] > 0
             assert 0 < row["mean_occupancy"] <= 1

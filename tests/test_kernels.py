@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.quant import quantize, quantize_weight
+from repro.core.quant import QTensor, quantize, quantize_weight
 from repro.kernels import ops, qmatmul as K, ref
 
 
@@ -67,8 +67,9 @@ def test_w8a8_integer_accumulate_exact():
     w = jax.random.randint(jax.random.fold_in(key, 1), (256, 128),
                            -127, 127, jnp.int8)
     one = jnp.ones((), jnp.float32)
-    got = K.qmatmul_w8a8(x, w, one, jnp.ones((128,)), None,
-                         interpret=True, out_dtype=jnp.float32)
+    got = K.qmatmul_w8a8(x, w[None], one, jnp.ones((1, 1, 128)),
+                         jnp.int32(0), None, interpret=True,
+                         out_dtype=jnp.float32)
     want = jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32).astype(jnp.float32)
@@ -117,6 +118,41 @@ def test_quantized_matmul_error_vs_fp_bounded(seed, shape, scale):
     want = x @ w_fp
     rel = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
     assert rel < 0.02
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("mode", ["w8a16", "w8a8"])
+def test_layer_stack_read_in_place_matches_ref(mode, layer):
+    """A (3, 256, 200) int8 stack read at each layer through the kernel
+    body, with bn = 128 so the last column block is ragged (200 = 128 +
+    72): equal to the oracle on the layer's own matrix, and the stack of
+    one built from that matrix gives the same result."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    x = _rand(keys[0], (16, 256))
+    w = quantize_weight(_rand(keys[1], (3, 256, 200)))
+    b = _rand(keys[2], (200,))
+    one = QTensor(values=w.values[layer], scale=w.scale[layer], bits=8)
+    xq = quantize(x, bits=8, axis=None) if mode == "w8a8" else None
+    got = ops.qmatmul(x, w, b, layer=jnp.int32(layer), x_q=xq,
+                      interpret=True, out_dtype=jnp.float32, bn=128)
+    flat = ops.qmatmul(x, one, b, x_q=xq, interpret=True,
+                       out_dtype=jnp.float32, bn=128)
+    if mode == "w8a8":
+        want = ref.qmatmul_w8a8_ref(xq.values, one.values, xq.scale,
+                                    one.scale.reshape(-1), b,
+                                    out_dtype=jnp.float32)
+    else:
+        want = ref.qmatmul_w8a16_ref(x, one.values, one.scale.reshape(-1),
+                                     b, out_dtype=jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(flat))
+    # the CPU oracle path indexes the stack: bitwise its answer on the
+    # layer's own matrix
+    cpu = ops.qmatmul(x, w, b, layer=jnp.int32(layer), x_q=xq,
+                      out_dtype=jnp.float32)
+    cpu_flat = ops.qmatmul(x, one, b, x_q=xq, out_dtype=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(cpu), np.asarray(cpu_flat))
 
 
 def test_cpu_fallback_matches_interpret():

@@ -103,6 +103,40 @@ def test_decode_matches_forward(arch):
     assert err < 0.05, f"{arch}: decode/forward relative gap {err:.4f}"
 
 
+@pytest.mark.parametrize("interpret", [False, True])
+@pytest.mark.parametrize("arch", ["mamba2-1.3b", "granite-4.0-h-small"])
+def test_in_proj_padding_is_idempotent_and_changes_no_logit(arch,
+                                                            interpret):
+    """The served layout pads the int8 SSD in-projection (552 columns at
+    the reduced widths) with zero columns to 640, once: a second pass
+    leaves it as it is, and decode logits with and without the padding
+    are equal, on the CPU oracle and through the kernel body."""
+    from repro.core.qlinear import QuantMode
+    cfg = get_config(arch).reduced()
+    params = quantize_tree(R.init(KEY, cfg), min_size=2048)
+    padded = R.serving_params(cfg, params)
+    block = "layers" if cfg.family == "ssm" else "mamba"
+    w, wp = params[block]["in_proj"]["w"], padded[block]["in_proj"]["w"]
+    width = 2 * cfg.d_inner + 2 * cfg.ssm_state + cfg.ssm_heads
+    assert w.shape[-1] == width == 552 and wp.shape[-1] == 640
+    np.testing.assert_array_equal(np.asarray(wp.values[..., width:]), 0)
+    np.testing.assert_array_equal(np.asarray(wp.values[..., :width]),
+                                  np.asarray(w.values))
+    again = R.serving_params(cfg, padded)
+    assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(again),
+                                      jax.tree_util.tree_leaves(padded)))
+    mode = QuantMode(enabled=True, act_bits=16, interpret=interpret)
+    tokens = jax.random.randint(KEY, (2, 3), 0, cfg.vocab)
+    caches = [R.init_cache(cfg, 2, 16), R.init_cache(cfg, 2, 16)]
+    for i in range(3):
+        d = {"tokens": tokens[:, i:i + 1], "cache_index": jnp.array(i)}
+        outs = []
+        for j, p in enumerate((params, padded)):
+            lg, caches[j] = R.apply_decode(p, cfg, d, caches[j], mode=mode)
+            outs.append(np.asarray(lg))
+        np.testing.assert_array_equal(outs[0], outs[1])
+
+
 class TestSSDOracle:
     """The chunked SSD algorithm vs a naive sequential recurrence."""
 

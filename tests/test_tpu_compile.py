@@ -9,6 +9,7 @@ process may load the TPU library at a time, and every test worker
 imports this module.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -52,27 +53,71 @@ def _compile(one_chip, fn, *shapes):
     return text
 
 
+# (layers, K, N): each projection as a stack of one, and the SSD
+# in-projection stacks of mamba2-1.3b (8576: 8512 padded to 128s) and
+# granite-4.0-h-small (16768), read in place with a ragged last column block
+STACKS = [pytest.param(1, k, n, id=f"{k}-{n}") for k, n in PROJ] + [
+    pytest.param(48, 2048, 8576, id="48x2048-8576"),
+    pytest.param(36, 4096, 16768, id="36x4096-16768")]
+
+
 @pytest.mark.parametrize("m", [8, 2048])
-@pytest.mark.parametrize("k,n", PROJ)
+@pytest.mark.parametrize("layers,k,n", STACKS)
 @pytest.mark.parametrize("mode", ["w8a16", "w8a8"])
-def test_qmatmul_compiles(one_chip, mode, k, n, m):
+def test_qmatmul_compiles(one_chip, mode, layers, k, n, m):
     x_dtype = "int8" if mode == "w8a8" else "bf16"
     tc = AT.best_config(m, k, n, mode=mode, x_dtype="bf16",
                         out_dtype="bf16", has_bias=True,
                         cache=AT.AutotuneCache(""))
-    mp, kp, np_ = (-(-m // tc.bm) * tc.bm, -(-k // tc.bk) * tc.bk,
-                   -(-n // tc.bn) * tc.bn)
+    assert k % tc.bk == 0, tc
+    mp = -(-m // tc.bm) * tc.bm
+    layer = jnp.int32(layers - 1)
     if mode == "w8a8":
         fn = lambda x, w, ws, b: QM.qmatmul_w8a8(
-            x, w, jnp.ones((), jnp.float32), ws, b, out_dtype=jnp.bfloat16,
-            **tc.as_kwargs())
+            x, w, jnp.ones((), jnp.float32), ws, layer, b,
+            out_dtype=jnp.bfloat16, **tc.as_kwargs())
     else:
         fn = lambda x, w, ws, b: QM.qmatmul_w8a16(
-            x, w, ws, b, out_dtype=jnp.bfloat16, **tc.as_kwargs())
-    _compile(one_chip, fn,
-             ((mp, kp), jnp.int8 if x_dtype == "int8" else jnp.bfloat16),
-             ((kp, np_), jnp.int8), ((np_,), jnp.float32),
-             ((np_,), jnp.float32))
+            x, w, ws, layer, b, out_dtype=jnp.bfloat16, **tc.as_kwargs())
+    text = _compile(one_chip, fn,
+                    ((mp, k), jnp.int8 if x_dtype == "int8" else jnp.bfloat16),
+                    ((layers, k, n), jnp.int8), ((layers, 1, n), jnp.float32),
+                    ((n,), jnp.float32))
+    assert f"%qmatmul_{mode}" in text
+    assert not _int8_weight_copies(text, [(layers, k, n)])
+
+
+def _int8_shapes(params):
+    return [x.shape for x in jax.tree_util.tree_leaves(params)
+            if x.dtype == jnp.int8]
+
+
+def _int8_weight_copies(text, weights):
+    """The ops of a compiled program (outside fusion bodies) that write an
+    int8 array shaped like a weight: a copy, pad, slice or dynamic slice,
+    or a fusion named for one, whose result is a whole stack of
+    ``weights`` ((L, K, N) shapes) or one layer's (K, N), padded or not."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    kn = {tuple(w[-2:]) for w in weights}
+    found, comp = [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        op = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = s8\[([\d,]*)\]\S* "
+                      r"([\w-]+)\(", line)
+        if not op or comp in fused:
+            continue
+        name, dims, opcode = op.group(1), op.group(2), op.group(3)
+        if not (opcode in ("copy", "pad", "slice", "dynamic-slice")
+                or (opcode == "fusion" and "slice" in name)):
+            continue
+        dims = tuple(int(d) for d in dims.split(",") if d)
+        if any(dims[-2] == k and n <= dims[-1] < n + 512 for k, n in kn
+               if len(dims) >= 2):
+            found.append(name)
+    return found
 
 
 B, S, BS = 8, 256, 16
@@ -146,8 +191,10 @@ def test_int8_kv_slot_step_compiles_and_fits(one_chip, monkeypatch):
 def test_ssm_slot_step_updates_state_in_place(one_chip, monkeypatch):
     """The mamba2-1.3b slot step at published widths (32 slots, W8A16,
     depth cut to 4): the state kernel is in it, the float32 state is
-    updated in the donated buffer, and no temporary holds as much as one
-    layer's state."""
+    updated in the donated buffer, no temporary holds as much as one
+    layer's state, and the int8 linears read their layer stacks in place:
+    no weight is sliced, relaid out or padded (the in-projection is
+    stored at 8576 columns, 8512 padded to a multiple of 128)."""
     from repro.core.qlinear import W8A16
     from repro.kernels import ops
     from repro.models import registry as R
@@ -168,10 +215,14 @@ def test_ssm_slot_step_updates_state_in_place(one_chip, monkeypatch):
     compiled = step.lower(params, i32(slots, 1), cache, i32(slots),
                           jax.ShapeDtypeStruct((slots,), bool,
                                                sharding=one_chip)).compile()
-    calls = [ln for ln in compiled.as_text().splitlines()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln
              and "%ssm_state_update" in ln]
     assert calls, "the state kernel is not in the compiled step"
+    assert params["layers"]["in_proj"]["w"].shape == (4, 2048, 8576)
+    assert "%qmatmul_w8a16" in text
+    assert not _int8_weight_copies(text, _int8_shapes(params))
     nbytes = lambda x: x.size * x.dtype.itemsize
     layer_state = nbytes(cache["h"]) // cfg.n_layers        # 67 MB
     cache_bytes = sum(nbytes(x) for x in cache.values())
@@ -208,9 +259,11 @@ def test_granite_slot_step_reads_int8_experts_in_place(one_chip, monkeypatch):
     and one attention layer, each with its MoE block): the held-expert
     and state kernels are in it, no bf16 or float32 array of a held
     expert's weight shape exists in it (the kernel converts int8 tiles in
-    VMEM), and its temporaries stay under two layers' state (71 MB here,
+    VMEM), its temporaries stay under two layers' state (71 MB here,
     259 MB at all 40 layers): no copy of the state or of a weight
-    stack."""
+    stack, and the shared int8 linears (Mamba and attention projections,
+    shared MLP) read their layer stacks in place: no weight is sliced or
+    padded."""
     from repro.core.qlinear import W8A16
     from repro.kernels import ops
     from repro.models import registry as R
@@ -237,6 +290,8 @@ def test_granite_slot_step_reads_int8_experts_in_place(one_chip, monkeypatch):
                           if 'custom_call_target="tpu_custom_call"' in ln
                           and f"%{name}" in ln]
     assert calls("moe_w8a16") and calls("ssm_state_update")
+    assert calls("qmatmul_w8a16")
+    assert not _int8_weight_copies(text, _int8_shapes(params))
     d, f, e = cfg.d_model, cfg.d_ff, cfg.n_held
     for dims in ((e, d, 2 * f), (d, 2 * f), (e, f, d), (f, d)):
         shape = ",".join(map(str, dims))
